@@ -16,6 +16,14 @@ The paper's qualitative claims this must reproduce:
   * static runtime grows monotonically with pass-through count,
   * dynamic ≈ custom (operators contiguous + pipelined),
   * PR overhead excluded from the curve (measured in pr_overhead.py).
+
+    PYTHONPATH=src:. python -m benchmarks.fig3_vmul_reduce [--smoke]
+    PYTHONPATH=src:. python -m benchmarks.fig3_vmul_reduce --sharded
+
+``--sharded`` is its own invocation: it puts the process on 9 host CPU
+devices (the 3×3 overlay, every hop a real ``ppermute``) before JAX first
+touches a backend, and exits non-zero if it does not get them.  No variant
+starts a child process.
 """
 
 from __future__ import annotations
@@ -57,7 +65,7 @@ def scenarios(n: int):
     ]
 
 
-def bench_size(n: int, label: str) -> tuple[list[str], float, list[float]]:
+def bench_size(n: int, label: str) -> list[str]:
     rows = []
     key = jax.random.PRNGKey(0)
     a = jax.random.normal(key, (n,))
@@ -65,32 +73,29 @@ def bench_size(n: int, label: str) -> tuple[list[str], float, list[float]]:
 
     g, grid, fixed = scenarios(n)
 
-    static_us = []
     for name, placement in fixed:
         pl = place_static(g, grid, placement)
         acc = assemble(g, pl)
         us = time_call(jax.jit(acc.fn), a, b)
-        static_us.append(us)
         rows.append(row(f"fig3/{label}/{name}", us,
                         f"passthrough={pl.total_passthrough}"))
 
     pl = place_dynamic(g, grid)
     acc = assemble(g, pl)
-    us_dyn = time_call(jax.jit(acc.fn), a, b)
-    rows.append(row(f"fig3/{label}/dynamic", us_dyn,
+    rows.append(row(f"fig3/{label}/dynamic", time_call(jax.jit(acc.fn), a, b),
                     f"passthrough={pl.total_passthrough}"))
 
     custom = jax.jit(lambda a, b: jnp.sum(a * b))
     rows.append(row(f"fig3/{label}/custom_hls", time_call(custom, a, b),
                     "monolithic_jit"))
 
-    if n <= 1024 * 1024:   # interpret-mode pallas is python-speed per block
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu or n <= 1024 * 1024:  # interpreted pallas is python-speed per block
         from repro.kernels import ops as kops
         rows.append(row(
             f"fig3/{label}/pallas_fused",
-            time_call(jax.jit(
-                lambda a, b: kops.vmul_reduce(a, b, interpret=True)),
-                a, b), "interpret_mode"))
+            time_call(jax.jit(kops.vmul_reduce), a, b),
+            "compiled" if on_tpu else "interpret_mode"))
 
     an, bn = np.asarray(a), np.asarray(b)
     import time as _t
@@ -100,95 +105,70 @@ def bench_size(n: int, label: str) -> tuple[list[str], float, list[float]]:
         float(np.dot(an, bn))
     rows.append(row(f"fig3/{label}/software_numpy",
                     (_t.perf_counter() - t0) / iters * 1e6, "eager"))
-    return rows, us_dyn, static_us
-
-
-def sharded_main() -> None:
-    """Subprocess entry: 9 host 'devices' = the 3×3 overlay; every hop is a
-    REAL ``ppermute`` transfer between devices (the ICI-faithful mode)."""
-    import jax as _jax
-
-    from repro.core import assemble_sharded, wrap_sharded
-
-    n = 4 * 1024 * 1024  # 16 MB per vector: transfers dominate, compute tiny
-    mesh = _jax.make_mesh((9,), ("tiles",))
-    key = _jax.random.PRNGKey(0)
-    a = _jax.random.normal(key, (n,))
-    b = _jax.random.normal(_jax.random.PRNGKey(1), (n,))
-
-    g, grid, fixed = scenarios(n)
-    out = []
-    for name, placement in fixed:
-        pl = place_static(g, grid, placement)
-        acc = assemble_sharded(g, pl, mesh)
-        fn = wrap_sharded(acc, g, mesh)
-        with mesh:
-            us = time_call(fn, a, b, warmup=2, iters=8)
-        out.append(row(f"fig3/sharded_16MB/{name}", us,
-                       f"hops={pl.total_hops}"))
-    pl = place_dynamic(g, grid)
-    acc = assemble_sharded(g, pl, mesh)
-    fn = wrap_sharded(acc, g, mesh)
-    with mesh:
-        us = time_call(fn, a, b, warmup=2, iters=8)
-    out.append(row("fig3/sharded_16MB/dynamic", us, f"hops={pl.total_hops}"))
-    print("\n".join(out))
-
-
-def run_sharded_subprocess() -> list[str]:
-    """Launch the sharded variant with 9 forced host devices (device count
-    is locked at first jax init, so it needs its own process)."""
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=9 "
-                        + env.get("XLA_FLAGS", ""))
-    env["REPRO_FIG3_SHARDED"] = "1"
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.fig3_vmul_reduce"],
-        capture_output=True, text=True, env=env, timeout=420)
-    lines = [l for l in proc.stdout.splitlines() if l.startswith("fig3/")]
-    if proc.returncode != 0 or not lines:
-        return [row("fig3/sharded_16MB/FAILED", -1.0,
-                    proc.stderr.splitlines()[-1][:80] if proc.stderr else "")]
-    return lines
-
-
-def main(smoke: bool = False) -> list[str]:
-    rows = []
-    if smoke:
-        # tiny single-process pass: every local code path executes, the
-        # heavy 9-device sharded subprocess is skipped (tests cover it)
-        r, _, _ = bench_size(1024, "smoke")
-        return r
-    # the paper's exact data size (16 KB): pass-through cost is sub-µs on a
-    # CPU cache, so this point reproduces the SETUP but not the separation
-    r, _, _ = bench_size(PAPER_VECTOR_LEN, "16KB_paper")
-    rows += r
-    # sharded mode: 9 devices = 3×3 overlay, hops are REAL inter-device
-    # ppermute transfers — this is where Fig. 3's separation reproduces
-    shard_rows = run_sharded_subprocess()
-    rows += shard_rows
-
-    stat = [float(r.split(",")[1]) for r in shard_rows if "static" in r]
-    dyn = [float(r.split(",")[1]) for r in shard_rows if "dynamic" in r]
-    if stat and dyn and min(stat) > 0:
-        ok_monotone = all(stat[i] <= stat[i + 1] * 1.15
-                          for i in range(len(stat) - 1))
-        ok_dyn = dyn[0] <= min(stat) * 1.1
-        rows.append(row("fig3/claim_static_monotone_in_passthrough", 0.0,
-                        f"holds={ok_monotone}"))
-        rows.append(row("fig3/claim_dynamic_beats_static", 0.0,
-                        f"holds={ok_dyn}"))
     return rows
 
 
-if __name__ == "__main__":
+def sharded_main() -> int:
+    """The ``--sharded`` invocation: 9 host devices = the 3×3 overlay; every
+    hop is a REAL ``ppermute`` transfer between devices (the ICI-faithful
+    mode), where Fig. 3's separation reproduces."""
     import os
-    if os.environ.get("REPRO_FIG3_SHARDED") == "1":
-        sharded_main()
-    else:
-        from benchmarks.common import bench_cli
-        bench_cli(main)
+
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=9 "
+                               + os.environ.get("XLA_FLAGS", ""))
+    jax.config.update("jax_platforms", "cpu")
+    if len(jax.devices()) != 9:
+        print(f"fig3 --sharded: needs 9 host devices, got "
+              f"{len(jax.devices())} (was JAX already initialised?)")
+        return 1
+
+    from repro.compat import make_mesh
+    from repro.core import assemble_sharded, wrap_sharded
+
+    n = 4 * 1024 * 1024  # 16 MB per vector: transfers dominate, compute tiny
+    mesh = make_mesh((9,), ("tiles",))
+    a = jax.random.normal(jax.random.PRNGKey(0), (n,))
+    b = jax.random.normal(jax.random.PRNGKey(1), (n,))
+
+    g, grid, fixed = scenarios(n)
+    rows, stat = [], []
+    for name, placement in fixed:
+        pl = place_static(g, grid, placement)
+        fn = wrap_sharded(assemble_sharded(g, pl, mesh), g, mesh)
+        with mesh:
+            us = time_call(fn, a, b, warmup=2, iters=8)
+        stat.append(us)
+        rows.append(row(f"fig3/sharded_16MB/{name}", us,
+                        f"hops={pl.total_hops}"))
+    pl = place_dynamic(g, grid)
+    fn = wrap_sharded(assemble_sharded(g, pl, mesh), g, mesh)
+    with mesh:
+        dyn = time_call(fn, a, b, warmup=2, iters=8)
+    rows.append(row("fig3/sharded_16MB/dynamic", dyn, f"hops={pl.total_hops}"))
+    ok_monotone = all(stat[i] <= stat[i + 1] * 1.15
+                      for i in range(len(stat) - 1))
+    rows.append(row("fig3/claim_static_monotone_in_passthrough", 0.0,
+                    f"holds={ok_monotone}"))
+    rows.append(row("fig3/claim_dynamic_beats_static", 0.0,
+                    f"holds={dyn <= min(stat) * 1.1}"))
+    print("name,us_per_call,derived")
+    print("\n".join(rows))
+    return 0
+
+
+def main(smoke: bool = False) -> list[str]:
+    if smoke:
+        # tiny single-process pass: every local code path executes
+        return bench_size(1024, "smoke")
+    # the paper's exact data size (16 KB): pass-through cost is sub-µs on a
+    # CPU cache, so this point reproduces the SETUP but not the separation;
+    # the separation is the --sharded invocation's
+    return bench_size(PAPER_VECTOR_LEN, "16KB_paper")
+
+
+if __name__ == "__main__":
+    import sys
+    if "--sharded" in sys.argv[1:]:
+        raise SystemExit(sharded_main())
+    from benchmarks.common import bench_cli
+    bench_cli(main)

@@ -1,53 +1,38 @@
-"""Version-compatibility shims for the moving JAX API surface.
+"""The one home of JAX names whose spelling moves between releases.
 
-``shard_map`` has lived in three places across recent JAX releases:
+Written against the installed JAX (0.9).  Every module of the repo that
+needs one of these names imports it from here, so a JAX upgrade edits this
+file and nothing else:
 
-* ``jax.experimental.shard_map.shard_map`` with a ``check_rep=`` kwarg
-  (the 0.4.x line this repo's CI pins),
-* ``jax.shard_map`` promoted to the top level, still ``check_rep=``,
-* ``jax.shard_map`` with the kwarg renamed to ``check_vma=`` (and the
-  experimental module removed).
-
-Every in-repo caller goes through :func:`shard_map` below, which resolves
-the callable once at import and translates the replication-check kwarg to
-whatever the installed JAX spells it.  Keep new ``shard_map`` call sites on
-this shim — raw ``jax.shard_map(...)`` is exactly the AttributeError that
-broke the distributed test lane.
+* the jaxpr classes the trace frontend walks: ``ClosedJaxpr``, ``Jaxpr``
+  and ``Literal`` live in ``jax.extend.core``; ``DropVar`` is not exported
+  there and is still read from ``jax.core``;
+* :func:`make_mesh`, whose axes are ``Auto`` (``jax.make_mesh`` now
+  defaults to ``Explicit`` axes, under which ``with_sharding_constraint``
+  on an un-annotated value is refused);
+* :func:`shard_map`, ``jax.shard_map`` with its ``check_vma`` flag.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable
+from typing import Sequence
 
 import jax
+from jax.core import DropVar
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
+from jax.sharding import AxisType
 
-try:
-    _shard_map = jax.shard_map                      # newest line: top level
-except AttributeError:                               # pragma: no cover - by version
-    from jax.experimental.shard_map import shard_map as _shard_map
+shard_map = jax.shard_map
 
-try:
-    _PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-except (TypeError, ValueError):                      # pragma: no cover
-    _PARAMS = frozenset()
-
-# the replication/varying-manual-axes check kwarg, under its local name
-_CHECK_KW = ("check_vma" if "check_vma" in _PARAMS
-             else "check_rep" if "check_rep" in _PARAMS
-             else None)
+__all__ = ["ClosedJaxpr", "DropVar", "Jaxpr", "Literal", "make_mesh",
+           "shard_map"]
 
 
-def shard_map(f: Callable[..., Any], *, mesh: Any, in_specs: Any,
-              out_specs: Any, check_vma: bool | None = None,
-              **kwargs: Any) -> Callable[..., Any]:
-    """``jax.shard_map`` across JAX versions.
-
-    ``check_vma`` follows the newest spelling; it is forwarded as
-    ``check_rep=`` on JAX lines that predate the rename and dropped entirely
-    if the installed ``shard_map`` accepts neither.
-    """
-    if check_vma is not None and _CHECK_KW is not None:
-        kwargs.setdefault(_CHECK_KW, check_vma)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Sequence | None = None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: shardings come from the
+    ``in_shardings``/``with_sharding_constraint`` the program states, as
+    the sharding rules in :mod:`repro.sharding` expect."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from repro.core.graph import Graph
 from repro.core.isa import Opcode, Program, compile_graph
 from repro.core.placement import Placement
+from repro.core.trace import PROJ_PREFIX
 
 
 # --------------------------------------------------------------------------
@@ -259,10 +260,20 @@ def build_kernel(graph: Graph, *,
     valid for *every* placement of ``graph`` — the TPU analogue of the
     paper's pre-synthesized bitstream being downloadable into any compatible
     PR region.  Relocation swaps the routes vector; the executable stays.
+
+    Only edges between two placed nodes carry a hop: graph inputs and
+    constants are never placed, so their edges stream straight in for every
+    placement (their routes entries are always 0).  Routing them anyway
+    would push every weight through a loop carry, and XLA copies a
+    read-only argument into each carry: at published widths that is one
+    extra copy of the model's weights per call.  An edge into a projection
+    moves only the element projected, not the whole tuple-valued residue
+    (selection commutes with copy passes).
     """
     nodes = graph.toposorted()
     eidx = {e: i for i, e in enumerate(edge_order(graph))}
     hop = hop_fn or _dyn_barrier_hops
+    unplaced = {n.node_id for n in nodes if n.kind in ("input", "const")}
 
     def kernel(routes, *inputs):
         vals: dict[int, Any] = dict(zip(graph.input_ids, inputs))
@@ -272,9 +283,13 @@ def build_kernel(graph: Graph, *,
             if n.kind == "const":
                 vals[n.node_id] = n.payload
                 continue
-            args = []
-            for src in n.inputs:
-                args.append(hop(vals[src], routes[eidx[(src, n.node_id)]]))
+            route = lambda src: routes[eidx[(src, n.node_id)]]
+            if n.kind == "op" and n.op.name.startswith(PROJ_PREFIX):
+                (src,) = n.inputs                # a multi-result residue
+                vals[n.node_id] = hop(n.op.fn(vals[src]), route(src))
+                continue
+            args = [vals[src] if src in unplaced else hop(vals[src], route(src))
+                    for src in n.inputs]
             if n.kind == "op":
                 vals[n.node_id] = n.op.fn(*args)
             elif n.kind == "select":
@@ -296,56 +311,24 @@ def _opaque_one(routes) -> Any:
     return routes[0].astype(jnp.float32) * 0.0 + 1.0
 
 
-# Library operators whose result can never be a bare LLVM ``fmul`` (safe
-# TAILS: fusing straight across their output edge cannot form an FMA), and
-# operators that never begin by ``fadd``/``fsub``-ing an operand (safe
-# HEADS).  Everything NOT listed — ``mul`` itself, ``neg`` (LLVM rewrites
-# fneg∘fmul into an fmul), ``pow[..]``, reductions, shape movers
-# (transparent to the fusion emitter), traced-residue and custom-kernel
-# nodes — is conservatively treated as contraction-prone.
-_CONTRACTION_SAFE_TAILS = frozenset({
-    "add", "sub", "div", "max", "min", "abs", "relu", "sigmoid", "silu",
-    "gelu", "sqrtf", "sin", "cos", "log", "exp", "rsqrt", "tanh",
-    "gt", "lt", "ge", "le", "eq", "ne"})
-_CONTRACTION_SAFE_HEADS = frozenset({
-    "mul", "div", "max", "min", "neg", "abs", "relu", "sigmoid", "silu",
-    "gelu", "sqrtf", "sin", "cos", "log", "exp", "rsqrt", "tanh",
-    "gt", "lt", "ge", "le", "eq", "ne"})
-
-
-def _contraction_guard_needed(producer, consumer) -> bool:
-    """Whether fusing straight across the (producer → consumer) edge could
-    let LLVM contract a cross-node mul+add pair into an FMA — the one
-    fusion-dependent rounding change.  The generic tier's per-edge loops
-    are fusion boundaries, so an unguarded contraction would make the
-    specialized tier drift from it by ULPs."""
-    if producer.kind in ("input", "const", "select"):
-        return False                 # parameters/constants/selects: no fmul
-    pname = producer.op.name if producer.op is not None else ""
-    if pname in _CONTRACTION_SAFE_TAILS:
-        return False
-    if consumer.kind == "select":
-        return False                 # llvm select: no fadd on the operand
-    cname = consumer.op.name if consumer.kind == "op" and \
-        consumer.op is not None else ""
-    return cname not in _CONTRACTION_SAFE_HEADS
-
-
 def _static_barrier_hops(one) -> Callable[[Any, int, bool], Any]:
     """Route-constant local mode: ``h`` is a Python int at trace time, so
     the generic tier's per-edge ``fori_loop``/dynamic-trip-count carcass is
     gone and XLA fuses the whole body into one kernel.  Pass-through-free
-    edges (``h <= 1``) vanish entirely unless they need the exactness
-    guard; ``h >= 2`` edges keep their h-1 physical copy passes (the
-    pass-through cost model), now statically unrolled.
+    edges (``h <= 1``) shrink to the exactness guard; ``h >= 2`` edges keep
+    their h-1 physical copy passes (the pass-through cost model), now
+    statically unrolled.
 
     The guard preserves bit-identity across tiers: the generic kernel's
-    zero-trip loops are *fusion boundaries*, and without them LLVM
-    contracts cross-node ``mul``+``add`` pairs into FMAs, drifting by
-    ULPs.  Guarded edges multiply by ``one`` — the runtime-opaque exact
-    1.0 — so any contraction instead computes ``fma(x, 1.0, c) ==
-    round(x + c)``: exact, and the fused specialized body reproduces the
-    generic tier bit for bit.  Non-float edges cannot contract."""
+    zero-trip loops are *fusion boundaries*.  Fused straight across an
+    edge, LLVM contracts a cross-node ``mul``+``add`` into an FMA and
+    XLA's simplifier rewrites cross-node patterns (``exp(a)*exp(b)`` into
+    ``exp(a+b)``, seeing through ``max(x, x)``), each drifting by ULPs.
+    So every edge out of a computed node multiplies by ``one`` — the
+    runtime-opaque exact 1.0: no pattern matches through it, and a
+    contraction computes ``fma(x, 1.0, c) == round(x + c)``, exact.  The
+    fused specialized body reproduces the generic tier bit for bit.
+    Non-float edges need no guard."""
     def hop_fn(v, h: int, guard: bool):
         def one_leaf(leaf):
             if not jnp.issubdtype(jnp.result_type(leaf), jnp.floating):
@@ -404,9 +387,9 @@ def specialize_kernel(graph: Graph, hops: "tuple[int, ...]", *,
     no hop count is ever READ from the runtime routes vector, so the
     ``fori_loop`` routing structure vanishes and XLA fuses the whole body.
     The routes argument survives only as the seed of the opaque exact-1.0
-    guarding contraction-prone edges (:func:`_contraction_guard_needed`) —
-    on a guard-free contiguous graph it is entirely unused and XLA drops
-    the parameter.  Keeping one calling convention across tiers also means
+    guarding every edge out of a computed node (see
+    :func:`_static_barrier_hops`).  Keeping one calling convention across
+    tiers also means
     donation kwargs, route binding and dispatch records need no per-tier
     cases.
 
@@ -422,8 +405,8 @@ def specialize_kernel(graph: Graph, hops: "tuple[int, ...]", *,
         raise ValueError(
             f"hop vector has {len(hops)} entries for {len(order)} edges")
     static_hops = {e: int(h) for e, h in zip(order, hops)}
-    guards = {e: _contraction_guard_needed(by_id[e[0]], by_id[e[1]])
-              for e in order}
+    # graph inputs and constants are never fused with a producer
+    guards = {e: by_id[e[0]].kind not in ("input", "const") for e in order}
     needs_one = any(g or static_hops[e] >= 2 for e, g in guards.items())
     factory = hop_factory or _static_barrier_hops
 

@@ -38,13 +38,14 @@ import hashlib
 from typing import Any, Callable
 
 import jax
-import jax.core as jcore
 
+from repro.compat import ClosedJaxpr, DropVar, Jaxpr, Literal
 from repro.core import patterns
 from repro.core.graph import Graph, NodeRef
 from repro.core.patterns import Operator, TileClass
 
 RESIDUE_PREFIX = "xla["
+PROJ_PREFIX = "proj["
 
 # call-style primitives whose sub-jaxpr we inline (NOT loop/branch primitives
 # like scan/while/cond, whose sub-jaxprs have different calling conventions —
@@ -76,15 +77,15 @@ class Lowered:
         return len(self.unmapped)
 
 
-def _as_closed(obj) -> jcore.ClosedJaxpr | None:
-    if isinstance(obj, jcore.ClosedJaxpr):
+def _as_closed(obj) -> ClosedJaxpr | None:
+    if isinstance(obj, ClosedJaxpr):
         return obj
-    if isinstance(obj, jcore.Jaxpr):
-        return jcore.ClosedJaxpr(obj, ())
+    if isinstance(obj, Jaxpr):
+        return ClosedJaxpr(obj, ())
     return None
 
 
-def _callee(eqn) -> tuple[jcore.ClosedJaxpr | None, str | None]:
+def _callee(eqn) -> tuple[ClosedJaxpr | None, str | None]:
     """Extract (sub_jaxpr, callee_name) from a call-style equation."""
     if eqn.primitive.name not in _CALL_PRIMITIVES:
         return None, None
@@ -112,7 +113,7 @@ def _residue_operator(eqn) -> Operator:
 
 
 def _projection(i: int) -> Operator:
-    return Operator(name=f"proj[{i}]", arity=1,
+    return Operator(name=f"{PROJ_PREFIX}{i}]", arity=1,
                     fn=lambda t, _i=i: t[_i],
                     tile_class=TileClass.SMALL, flops_per_elem=0.0)
 
@@ -124,7 +125,7 @@ class _Lowering:
         self.unmapped: list[str] = []
 
     def _ref(self, env: dict, atom) -> NodeRef:
-        if isinstance(atom, jcore.Literal):
+        if isinstance(atom, Literal):
             return self.g.const(atom.val, name="lit")
         return NodeRef(self.g, env[atom])
 
@@ -174,7 +175,7 @@ class _Lowering:
                         inner[var] = self.g.const(val, name="const").node_id
                     self.lower_eqns(inner, sub.jaxpr.eqns)
                     for outvar, res in zip(eqn.outvars, sub.jaxpr.outvars):
-                        if isinstance(outvar, jcore.DropVar):
+                        if isinstance(outvar, DropVar):
                             continue
                         env[outvar] = self._ref(inner, res).node_id
                     continue
@@ -207,7 +208,7 @@ class _Lowering:
                     jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
                     for v in eqn.outvars)
                 for i, outvar in enumerate(eqn.outvars):
-                    if isinstance(outvar, jcore.DropVar):
+                    if isinstance(outvar, DropVar):
                         continue
                     pid = self.g.apply(_projection(i), node).node_id
                     self._set_aval(pid, outvar.aval)

@@ -1,8 +1,10 @@
 """Pallas TPU kernels — the LARGE-tile operator bitstreams.
 
-TPU (v5e) is the *target*; this container is CPU-only, so every kernel runs
-``interpret=True`` here (the kernel body executes in Python on CPU) and
-compiled-mode on real TPUs.  ``INTERPRET`` flips automatically.
+TPU (v5e) is the target.  Each kernel takes ``interpret=None`` by default,
+which :func:`interpret_mode` resolves when the kernel is traced: compiled
+for the chip when JAX's default backend is a TPU, interpreted (the kernel
+body runs as plain JAX ops) on any other backend.  Nothing is decided at
+import, so importing ``repro`` never initialises a backend.
 
 Kernel inventory (one module per compute hot-spot, each with a pure-jnp
 oracle in ``ref.py`` and a jitted public wrapper in ``ops.py``):
@@ -26,7 +28,12 @@ def register_overlay_bitstreams() -> None:
     """Idempotently register the Pallas kernels as overlay LARGE operators."""
     from repro.kernels import ops  # noqa: F401  — import side effect registers
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode(interpret: bool | None) -> bool:
+    """The ``interpret`` flag of one ``pallas_call``, decided at trace time:
+    an explicit value wins, else compile on a TPU and interpret elsewhere."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
 
 # MXU/VPU alignment constants (v5e): 128-lane registers, 128x128 systolic array.
 LANE = 128

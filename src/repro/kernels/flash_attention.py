@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -87,7 +87,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None) -> jax.Array:
     """Attention over (B, Hq, S, D) q and (B, Hkv, S, D) k/v with Hq % Hkv == 0."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     if hq % hkv:

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -26,7 +26,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps: float):
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
             block_rows: int = 128, interpret: bool | None = None) -> jax.Array:
     """RMSNorm over the last axis. x: (..., d), w: (d,)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     if w.ndim != 1 or x.shape[-1] != w.shape[0]:
         raise ValueError(f"shape mismatch: x {x.shape}, w {w.shape}")
     d = x.shape[-1]

@@ -25,19 +25,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, acum_ref, *, chunk: int):
     x = x_ref[0, 0].astype(jnp.float32)      # (L, p)
-    a = a_ref[0, 0].astype(jnp.float32)      # (L,)
+    a = a_ref[0, 0].astype(jnp.float32)      # (1, L): a row, lane-major
     bmat = b_ref[0, 0].astype(jnp.float32)   # (L, n)
     cmat = c_ref[0, 0].astype(jnp.float32)   # (L, n)
 
-    a_cum = jnp.cumsum(a)                                    # (L,)
-    seg = a_cum[:, None] - a_cum[None, :]                    # (L, L)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # a_cum[i] = Σ_{j≤i} a[j] as masked reductions of a broadcast square:
+    # over lanes it lands in a column, over sublanes (of the transpose) in
+    # a row — the two layouts the (L, L) segment sums need
+    a_rows = jnp.broadcast_to(a, (chunk, chunk))             # [i, j] = a[j]
+    cum_col = jnp.sum(jnp.where(lj <= li, a_rows, 0.0), axis=1,
+                      keepdims=True)                         # (L, 1)
+    a_cum = jnp.sum(jnp.where(li <= lj, a_rows.T, 0.0), axis=0,
+                    keepdims=True)                           # (1, L)
+    seg = cum_col - a_cum                                    # (L, L)
     # mask before exp (j>i entries have seg>0 -> overflow)
     decay = jnp.exp(jnp.where(li >= lj, seg, -jnp.inf))      # (L, L)
 
@@ -45,7 +52,8 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, acum_ref, *, chunk: int):
     y_ref[0, 0] = jnp.dot(scores, x,
                           preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    w = jnp.exp(a_cum[-1] - a_cum)[:, None]                  # (L, 1)
+    a_tot = jnp.sum(a, axis=1, keepdims=True)                # (1, 1)
+    w = jnp.exp(a_tot - cum_col)                             # (L, 1)
     st_ref[0, 0] = jnp.dot((bmat * w).T, x,
                            preferred_element_type=jnp.float32).astype(st_ref.dtype)
     acum_ref[0, 0] = a_cum.astype(acum_ref.dtype)
@@ -57,13 +65,14 @@ def ssd_chunk(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
 
     Args:
       x: (bh, nchunks, L, p) pre-discretized inputs (x·Δ).
-      a: (bh, nchunks, L) log-decay per step (Δ·A, ≤ 0).
+      a: (bh, nchunks, 1, L) log-decay per step (Δ·A, ≤ 0); the unit axis
+        keeps the block's last two dims equal to the array's (TPU tiling).
       b, c: (bh, nchunks, L, n) input/output projections.
     Returns:
       y_diag: (bh, nchunks, L, p), states: (bh, nchunks, n, p),
-      a_cum: (bh, nchunks, L).
+      a_cum: (bh, nchunks, 1, L).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     bh, nc, L, p = x.shape
     n = b.shape[-1]
     if L != chunk:
@@ -75,19 +84,19 @@ def ssd_chunk(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, L, p), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, L), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, L, n), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, L, n), lambda i, j: (i, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, L, p), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, n, p), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, L), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, nc, L, p), jnp.float32),
             jax.ShapeDtypeStruct((bh, nc, n, p), jnp.float32),
-            jax.ShapeDtypeStruct((bh, nc, L), jnp.float32),
+            jax.ShapeDtypeStruct((bh, nc, 1, L), jnp.float32),
         ],
         interpret=interpret,
     )(x, a, b, c)
@@ -120,8 +129,9 @@ def ssd(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
         return t
 
     xb, ab, bb, cb = to_bh(x, True), to_bh(a, False), to_bh(b, True), to_bh(c, True)
-    y_diag, states, a_cum = ssd_chunk(xb, ab, bb, cb, chunk=chunk,
+    y_diag, states, a_cum = ssd_chunk(xb, ab[:, :, None], bb, cb, chunk=chunk,
                                       interpret=interpret)
+    a_cum = a_cum[:, :, 0]                               # (bh, nc, L)
 
     # inter-chunk recurrence on (n, p) states — O(nc) sequential, tiny
     a_tot = a_cum[..., -1]                               # (bh, nc)

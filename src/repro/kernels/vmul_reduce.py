@@ -8,9 +8,10 @@ the intermediate).
 
 Tiling: inputs are viewed as (rows, LANE)-blocks; each grid step streams one
 (BLOCK_ROWS, 128) tile of A and B into VMEM, multiplies on the VPU and
-accumulates a per-lane partial in VMEM scratch; the final grid step folds the
-scratch into the (1, 1) output.  Accumulation is f32 regardless of input
-dtype (bf16-safe).
+accumulates a per-lane partial into the resident (1, 128) output block; the
+128 lane partials are summed outside the kernel (the TPU stores vectors,
+not scalars, to VMEM).  Accumulation is f32 regardless of input dtype
+(bf16-safe).
 """
 
 from __future__ import annotations
@@ -19,26 +20,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import INTERPRET, LANE
+from repro.kernels import LANE, interpret_mode
 
 
-def _kernel(a_ref, b_ref, o_ref, acc_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+def _kernel(a_ref, b_ref, o_ref):
+    # the output block maps to (0, 0) at every step, so it stays resident
+    # in VMEM across the grid and doubles as the accumulator
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     # VPU multiply + row-fold; keep a (1, LANE) partial per lane to stay 2D
-    acc_ref[...] += jnp.sum(a * b, axis=0, keepdims=True)
-
-    @pl.when(step == pl.num_programs(0) - 1)
-    def _fold():
-        o_ref[0, 0] = jnp.sum(acc_ref[...])
+    o_ref[...] += jnp.sum(a * b, axis=0, keepdims=True)
 
 
 def vmul_reduce(a: jax.Array, b: jax.Array, *, block_rows: int = 256,
@@ -46,7 +42,7 @@ def vmul_reduce(a: jax.Array, b: jax.Array, *, block_rows: int = 256,
     """Fused dot product of two 1-D vectors. Pads to a (rows, 128) view."""
     if a.shape != b.shape or a.ndim != 1:
         raise ValueError(f"expect equal 1-D shapes, got {a.shape} vs {b.shape}")
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode(interpret)
     n = a.shape[0]
 
     rows = max((n + LANE - 1) // LANE, 1)
@@ -67,9 +63,8 @@ def vmul_reduce(a: jax.Array, b: jax.Array, *, block_rows: int = 256,
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, LANE), jnp.float32)],
+        out_specs=pl.BlockSpec((1, LANE), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, LANE), jnp.float32),
         interpret=interpret,
     )(a2, b2)
-    return out[0, 0].astype(a.dtype)
+    return jnp.sum(out).astype(a.dtype)

@@ -6,7 +6,7 @@ touches jax device state — the dry-run sets XLA_FLAGS *before* first jax use.
 
 from __future__ import annotations
 
-import jax
+from repro.compat import make_mesh
 
 # v5e hardware constants used by the roofline layer
 PEAK_FLOPS_BF16 = 197e12        # per chip
@@ -17,9 +17,9 @@ ICI_BW = 50e9                   # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke paths (axes present, size 1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

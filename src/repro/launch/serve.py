@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.archs import smoke_config
 from repro.core import FleetOverlay, Overlay
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pm
 from repro.models.transformer import model_spec
 from repro.serving import Request, ServeEngine
@@ -66,6 +67,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-queue-delay", type=float, default=None,
                     help="shed requests queued longer than this (seconds)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.is_encdec:

@@ -19,6 +19,7 @@ import jax
 from repro.configs import get_config
 from repro.configs.archs import smoke_config
 from repro.data.pipeline import make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as mdl
 from repro.models import params as pm
 from repro.models.transformer import model_spec
@@ -65,6 +66,7 @@ def main(argv=None) -> int:
                     help="run the train step through the overlay JIT-assembly "
                          "frontend instead of a bare jax.jit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     spec = model_spec(cfg)

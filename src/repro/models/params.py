@@ -16,6 +16,7 @@ a 512-device mesh without ever allocating a parameter.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -64,7 +65,11 @@ def stack_tree(tree: Any, n: int) -> Any:
 
 
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=0)
 def _materialize(spec: ParamSpec, key) -> jax.Array:
+    # one compiled program per leaf: the f32 draw is fused into the cast, so
+    # the device never holds an f32 copy of a bf16 leaf (3.2 GB for the
+    # stacked MLP weights of a 3.8 B model) next to the leaves already made
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":

@@ -91,6 +91,26 @@ class EventLoopEngine(ServeEngine):
         super().resize(tile_budget)
         self._prefill_chunk.tile_budget = tile_budget
 
+    def warmup(self, prompt_lens: "tuple[int, ...]" = ()) -> None:
+        """Eagerly download the ragged decode step and the prefill-chunk
+        accelerator of every bucket the given prompt lengths will use (this
+        engine never calls the whole-prompt prefill the base class warms).
+        Shapes only; no-op without an overlay."""
+        if self.overlay is None:
+            return
+        super().warmup()
+        sds = lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
+                                             jnp.result_type(x))
+        params_a = jax.tree_util.tree_map(sds, self.params)
+        c1_a = jax.eval_shape(lambda: mdl.init_cache(self.cfg, 1,
+                                                     self.max_len))
+        sizes = {self._chunk_size(rem) for n in prompt_lens
+                 for rem in range(int(n), 0, -self.chunk)}
+        for size in sorted(sizes):
+            self._prefill_chunk.prefetch(
+                params_a, jax.ShapeDtypeStruct((1, size), jnp.int32), c1_a,
+                jax.ShapeDtypeStruct((), jnp.int32))
+
     # -- admission -----------------------------------------------------------
     def submit(self, req: Request) -> bool:
         """Queue a request, or shed it against the SLO bounds.

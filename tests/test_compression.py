@@ -48,10 +48,10 @@ def test_compressed_psum_across_pods():
     out = run_with_devices(4, """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from repro.compat import make_mesh, shard_map
         from repro.optim.compression import quantize, dequantize
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 128)) * 0.01
 
         def reduce_compressed(g_local):

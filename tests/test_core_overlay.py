@@ -242,3 +242,28 @@ def test_cache_keys_and_evict_keys():
     assert c.evict_keys(["x:1", "not-there"]) == 1
     assert "x:1" not in c and len(c) == 2
     assert c.stats.evictions == 1
+
+
+# ---------------------------------------------------------------------------
+# Generic kernel: what the routes vector can and cannot move
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("policy", list(PlacementPolicy))
+def test_generic_kernel_streams_inputs_without_copies(policy):
+    # graph inputs and constants are never placed, so no placement routes
+    # them; the generic kernel must not push a weight through a hop loop,
+    # whose carry XLA fills with a copy of the read-only argument
+    from repro.core import build_kernel, route_vector, trace_to_graph
+    x = jnp.ones((8, 256), jnp.float32)
+    w = jnp.ones((256, 1024), jnp.float32)
+    g = trace_to_graph(lambda x, w: jnp.tanh(x @ w) * 2.0 + 1.0, x, w).graph
+    pl = place(g, TileGrid(3, 3), policy)
+    routes = route_vector(g, pl)
+    unplaced = {n.node_id for n in g.nodes if n.kind in ("input", "const")}
+    for (src, _), h in zip(g.edges(), np.asarray(routes)):
+        if src in unplaced:
+            assert h == 0
+    compiled = jax.jit(build_kernel(g)).lower(routes, x, w).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < w.nbytes
+    np.testing.assert_allclose(np.asarray(compiled(routes, x, w)),
+                               np.tanh(np.asarray(x) @ np.asarray(w)) * 2.0
+                               + 1.0, rtol=1e-6)

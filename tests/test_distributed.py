@@ -51,7 +51,8 @@ def test_ep_moe_matches_local_moe():
 
         y_local, aux_local = moe_lib._moe_fwd_local(p, x, cfg)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         shd.set_active(mesh, shd.DEFAULT_RULES)
         with mesh:
             y_ep, aux_ep = jax.jit(
@@ -75,7 +76,8 @@ def test_sharded_overlay_matches_local():
         a = jax.random.normal(jax.random.PRNGKey(0), (4096,))
         b = jax.random.normal(jax.random.PRNGKey(1), (4096,))
         ref = assemble(g, pl)(a, b)
-        mesh = jax.make_mesh((9,), ("tiles",))
+        from repro.compat import make_mesh
+        mesh = make_mesh((9,), ("tiles",))
         acc = assemble_sharded(g, pl, mesh)
         fn = wrap_sharded(acc, g, mesh)
         with mesh:
@@ -104,7 +106,8 @@ def test_sharded_train_step_matches_single_device():
 
         loss_1dev, _ = mdl.loss_fn(params, batch, cfg)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         shd.set_active(mesh, shd.DEFAULT_RULES)
         with mesh:
             loss_mesh, _ = jax.jit(

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro import sharding as shd
+from repro.compat import make_mesh
 from repro.configs.archs import smoke_config
 from repro.core import Overlay
 from repro.data.pipeline import SyntheticLM
@@ -85,7 +86,43 @@ def test_overlay_reassembly_hits_bitstream_cache():
     assert ov.cache.stats.hits >= 1
 
 
-def test_train_driver_end_to_end(tmp_path):
+@pytest.fixture
+def entry_point_cache(tmp_path_factory, monkeypatch):
+    """An entry point turns on JAX's persistent compile cache for the whole
+    process: keep it out of the checkout and off for the tests after it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "CHECKOUT",
+                        tmp_path_factory.mktemp("checkout"))
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_comes_from_env_else_checkout(from_env, tmp_path,
+                                                        monkeypatch):
+    from repro.launch import compile_cache
+    set_here = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *kv: set_here.append(kv))
+    if from_env:
+        # JAX reads the variable itself; the helper must not override it
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert set_here == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.enable_compile_cache()
+        assert (compile_cache.CHECKOUT / "pyproject.toml").is_file()
+        assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert set_here == [("jax_compilation_cache_dir", path)]
+
+
+def test_train_driver_end_to_end(tmp_path, entry_point_cache):
     from repro.launch.train import main
     rc = main(["--arch", "minicpm-2b", "--smoke", "--steps", "8",
                "--batch", "4", "--seq", "32", "--ckpt-dir",
@@ -93,7 +130,7 @@ def test_train_driver_end_to_end(tmp_path):
     assert rc == 0
 
 
-def test_train_driver_survives_injected_failure(tmp_path):
+def test_train_driver_survives_injected_failure(tmp_path, entry_point_cache):
     from repro.launch.train import main
     rc = main(["--arch", "mamba2-130m", "--smoke", "--steps", "6",
                "--batch", "2", "--seq", "16", "--ckpt-dir", str(tmp_path),
@@ -105,7 +142,7 @@ def test_train_driver_survives_injected_failure(tmp_path):
 # sharding rules
 # ---------------------------------------------------------------------------
 def test_logical_to_spec_divisibility_dropping():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = shd.DEFAULT_RULES
     # axis of size 1 -> dropped entirely
     spec = shd.logical_to_spec(mesh, rules, ("batch", None), (4, 8))
@@ -117,7 +154,7 @@ def test_spec_drops_nondivisible_dims():
     devs = jax.devices()
     if len(devs) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # on a 1x1 mesh nothing shards, but the API contract holds:
     s = shd.named_sharding(mesh, shd.DEFAULT_RULES,
                            ("vocab", "embed"), (122753, 2304))

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core import Overlay, PlacementPolicy, saxpy_graph
 from repro.core.scheduler import DownloadScheduler
 
@@ -453,7 +454,7 @@ def test_mesh_overlay_forces_synchronous_mode():
     import jax
     if len(jax.devices()) < 1:                 # pragma: no cover
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("tiles",))
+    mesh = make_mesh((1,), ("tiles",))
     ov = Overlay(3, 3, mesh=mesh, async_downloads=True)
     assert not ov.async_downloads              # sharded assembly stays sync
 

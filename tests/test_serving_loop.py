@@ -118,6 +118,25 @@ def test_event_loop_prefill_chunk_sizes_bounded_by_bucket_set():
     assert 4 in sizes                          # long prompts use full chunks
 
 
+def test_event_loop_warmup_downloads_every_accelerator_traffic_uses():
+    """``warmup(prompt_lens)`` downloads the decode step plus the prefill
+    chunk of every bucket those prompts use (12 -> 16; 40 -> 16, 16, 8), so
+    serving them starts no download and touches no fallback."""
+    ov = Overlay(3, 3)
+    engine = EventLoopEngine(PARAMS, CFG, batch=2, max_len=64, chunk=16,
+                             overlay=ov)
+    engine.warmup((12, 40))
+    assert ov.stats.downloads == 3             # decode + chunks {16, 8}
+    for rid, n in enumerate((12, 40)):
+        engine.submit(Request(rid=rid, prompt=list(range(1, n + 1)),
+                              max_new_tokens=3))
+    done = engine.run_until_drained()
+    assert len(done) == 2
+    assert ov.stats.downloads == 3
+    assert ov.stats.fallback_calls == 0
+    assert ov.stats.prefetch_hits == 3
+
+
 def test_event_loop_fifo_and_recycling_under_oversubscription():
     """Sustained oversubscription through one slot: every request finishes
     (slot recycling) in submit order (FIFO within a priority class)."""

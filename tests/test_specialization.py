@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core import (Opcode, Overlay, PlacementPolicy, TileGrid,
                         build_kernel, compile_compute, compile_specialized,
                         place, place_static, route_hops, route_vector,
@@ -325,7 +326,7 @@ def test_defragment_enqueues_specialization_for_contiguous_residents():
 def test_sharded_overlay_specializes_bit_identical():
     # mesh mode: static hops unroll into ppermutes (no fori_loop/switch);
     # outputs must still match the generic collective kernel bit for bit
-    mesh = jax.make_mesh((len(jax.devices()),), ("tiles",))
+    mesh = make_mesh((len(jax.devices()),), ("tiles",))
     ov = Overlay(3, 3, mesh=mesh)
     jitted = ov.jit(lambda x, w: jnp.sqrt((x * w) ** 2 + 1.0), name="sh")
     x = jnp.linspace(0.1, 1.0, 64)

@@ -66,7 +66,9 @@ def test_specialization_bit_identical_property(data, seed):
     spec = jax.jit(specialize_kernel(g, hops))
     y1 = np.asarray(jax.block_until_ready(
         spec(route_vector(g, res.placement), *xs)))
-    assert np.array_equal(y0, y1)               # bit-identical across tiers
+    # compare bits, not values: a bit-identical NaN is still identical
+    bits = lambda y: y.view(np.uint32)
+    assert np.array_equal(bits(y0), bits(y1))   # bit-identical across tiers
 
     ins = ov.cache.stats.insertions
     try:
@@ -75,11 +77,11 @@ def test_specialization_bit_identical_property(data, seed):
         return                                  # no disjoint placement exists
     ov.relocate(g, new_pl)
     y2 = np.asarray(jax.block_until_ready(ov.assemble(g)(*xs)))
-    assert np.array_equal(y0, y2)               # zero drift through the cycle
+    assert np.array_equal(bits(y0), bits(y2))   # zero drift through the cycle
     assert ov.cache.stats.insertions == ins     # zero new kernel insertions
     # re-specialize at the NEW placement: still bit-identical
     res2 = ov.fabric.get(acc.resident_id)
     spec2 = jax.jit(specialize_kernel(g, route_hops(g, res2.placement)))
     y3 = np.asarray(jax.block_until_ready(
         spec2(route_vector(g, res2.placement), *xs)))
-    assert np.array_equal(y0, y3)
+    assert np.array_equal(bits(y0), bits(y3))
