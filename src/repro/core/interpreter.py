@@ -249,6 +249,13 @@ def _dyn_ici_hops(axis: str, n_dev: int) -> Callable[[Any, Any], Any]:
     return hop_fn
 
 
+def _name_after(kernel: Callable[..., Any], name: str) -> None:
+    """Name a kernel after the function it assembles: ``jax.jit`` names the
+    executable ``jit_<name>``, so a profile's device ops say which jitted
+    function, and which tier, they ran for."""
+    kernel.__name__ = kernel.__qualname__ = name
+
+
 def build_kernel(graph: Graph, *,
                  hop_fn: Callable[[Any, Any], Any] | None = None
                  ) -> Callable[..., Any]:
@@ -298,6 +305,7 @@ def build_kernel(graph: Graph, *,
         outs = tuple(vals[i] for i in graph.output_ids)
         return outs[0] if len(outs) == 1 else outs
 
+    _name_after(kernel, graph.name)
     return kernel
 
 
@@ -431,6 +439,7 @@ def specialize_kernel(graph: Graph, hops: "tuple[int, ...]", *,
         outs = tuple(vals[i] for i in graph.output_ids)
         return outs[0] if len(outs) == 1 else outs
 
+    _name_after(kernel, f"{graph.name}.specialized")
     return kernel
 
 

@@ -82,6 +82,10 @@ from repro.serving.metrics import Histogram
 # many attempts; the entry keeps serving from its fallback
 _MAX_DOWNLOAD_FAILURES = 3
 
+# a named span on the profiler's clock: with no profile recording, one
+# enter/exit and nothing else
+_span = jax.profiler.TraceAnnotation
+
 logger = logging.getLogger(__name__)
 
 
@@ -565,23 +569,26 @@ class JitAssembled:
         return None
 
     def __call__(self, *args):
-        presplit = self._split(args)
-        entry = self._entries.get(self._sig_key(presplit[0], presplit[2]))
-        if entry is not None:
-            rec = entry.record
-            if rec is not None:
-                res = rec.res
-                # the ENTIRE hot-path validation: liveness + one generation
-                # read (+ the wrapper's budget, when capped).  Anything that
-                # could invalidate the executable — evict, reclaim, flush,
-                # relocation, budget repack — changes one of these, and the
-                # stale record fails closed into the slow path below.
-                if res.live and res.generation == rec.generation and \
-                        (self.tile_budget is None
-                         or res.tile_budget == self.tile_budget):
-                    return self._dispatch_fast(args, entry, rec, res,
-                                               presplit)
-        return self._call_slow(args, presplit)
+        with _span("overlay.dispatch"):
+            presplit = self._split(args)
+            entry = self._entries.get(self._sig_key(presplit[0],
+                                                    presplit[2]))
+            if entry is not None:
+                rec = entry.record
+                if rec is not None:
+                    res = rec.res
+                    # the ENTIRE hot-path validation: liveness + one
+                    # generation read (+ the wrapper's budget, when capped).
+                    # Anything that could invalidate the executable — evict,
+                    # reclaim, flush, relocation, budget repack — changes one
+                    # of these, and the stale record fails closed into the
+                    # slow path below.
+                    if res.live and res.generation == rec.generation and \
+                            (self.tile_budget is None
+                             or res.tile_budget == self.tile_budget):
+                        return self._dispatch_fast(args, entry, rec, res,
+                                                   presplit)
+            return self._call_slow(args, presplit)
 
     def _dispatch_fast(self, args, entry: _JitEntry, rec: _DispatchRecord,
                        res: ResidentAccelerator, presplit):
@@ -615,7 +622,8 @@ class JitAssembled:
             if plan is not None and plan.fires("dispatch", res.rid):
                 raise FaultError(
                     f"injected dispatch failure on {res.rid!r}")
-            out = rec.fn(*flat)
+            with _span("overlay.execute"):
+                out = rec.fn(*flat)
         except (PlacementError, FabricError):
             raise
         except Exception as exc:
@@ -644,62 +652,69 @@ class JitAssembled:
             entry.record = None
         ov.stats.dispatch_fallbacks += 1
         ov.stats.fallback_calls += 1
-        out = entry.closed(*presplit[0])
+        with _span("overlay.fallback"):
+            out = entry.closed(*presplit[0])
         self._ensure_download(entry, args)
         return out
 
     def _call_slow(self, args, presplit):
-        entry = self._entry(args, _presplit=presplit)
-        entry.calls += 1               # the deterministic retry clock
-        ov = self.overlay
-        acc = entry.acc
-        if acc is None:
-            # nothing assembled yet: serve the request from the traced
-            # residue function, executed *eagerly* (the paper's "software
-            # fallback while the bitstream downloads").  Eager dispatch
-            # needs no whole-graph compile, so time-to-first-result never
-            # waits on XLA; the download is requested after the response is
-            # computed and the accelerator swaps in underneath.
-            ov.stats.fallback_calls += 1
-            out = entry.closed(*presplit[0])
-            self._ensure_download(entry, args)
-            return out
-        if not ov.resident_current(acc):
-            # mid-re-download: the prior-generation executable lost its PR
-            # regions but is still a correct pure function — keep serving
-            # it while the fabric re-downloads this signature
-            ov.stats.fallback_calls += 1
-            flat = jax.tree.leaves(presplit[0])
-            out = acc.fn(*flat)
-            self._ensure_download(entry, args)
-        else:
-            # a resident hit that missed the fast path (first dispatch, or
-            # a just-invalidated record): republish, then dispatch through
-            # the record so this call already serves the best live tier
-            ov._publish_record(entry)
-            rec = entry.record
-            fn = acc.fn if rec is None else rec.fn
-            if rec is not None and rec.tier == "specialized":
-                ov.cache.spec_stats.specialized_hits += 1
-            flat = jax.tree.leaves(presplit[0])
-            t0 = time.perf_counter()
-            try:
-                out = fn(*flat)
-            except (PlacementError, FabricError):
-                raise
-            except Exception as exc:
-                res = rec.res if rec is not None \
-                    else ov.fabric.get(acc.resident_id)
-                if res is None:
+        with _span("overlay.slow_path"):
+            entry = self._entry(args, _presplit=presplit)
+            entry.calls += 1               # the deterministic retry clock
+            ov = self.overlay
+            acc = entry.acc
+            if acc is None:
+                # nothing assembled yet: serve the request from the traced
+                # residue function, executed *eagerly* (the paper's "software
+                # fallback while the bitstream downloads").  Eager dispatch
+                # needs no whole-graph compile, so time-to-first-result never
+                # waits on XLA; the download is requested after the response is
+                # computed and the accelerator swaps in underneath.
+                ov.stats.fallback_calls += 1
+                with _span("overlay.fallback"):
+                    out = entry.closed(*presplit[0])
+                self._ensure_download(entry, args)
+                return out
+            if not ov.resident_current(acc):
+                # mid-re-download: the prior-generation executable lost its PR
+                # regions but is still a correct pure function — keep serving
+                # it while the fabric re-downloads this signature
+                ov.stats.fallback_calls += 1
+                flat = jax.tree.leaves(presplit[0])
+                with _span("overlay.execute"):
+                    out = acc.fn(*flat)
+                self._ensure_download(entry, args)
+            else:
+                # a resident hit that missed the fast path (first dispatch, or
+                # a just-invalidated record): republish, then dispatch through
+                # the record so this call already serves the best live tier
+                ov._publish_record(entry)
+                rec = entry.record
+                fn = acc.fn if rec is None else rec.fn
+                if rec is not None and rec.tier == "specialized":
+                    ov.cache.spec_stats.specialized_hits += 1
+                flat = jax.tree.leaves(presplit[0])
+                t0 = time.perf_counter()
+                try:
+                    with _span("overlay.execute"):
+                        out = fn(*flat)
+                except (PlacementError, FabricError):
                     raise
-                return self._dispatch_failed(entry, res, exc, args, presplit)
-            us = (time.perf_counter() - t0) * 1e6
-            if rec is not None and rec.res.dispatch_hist is not None:
-                rec.res.dispatch_hist.record(us)
-            ov.dispatch_hist.record(us)
-        n_out = len(entry.lowered.graph.output_ids)
-        leaves = list(out) if n_out > 1 else [out]
-        return jax.tree_util.tree_unflatten(entry.lowered.out_tree, leaves)
+                except Exception as exc:
+                    res = rec.res if rec is not None \
+                        else ov.fabric.get(acc.resident_id)
+                    if res is None:
+                        raise
+                    return self._dispatch_failed(entry, res, exc, args,
+                                                 presplit)
+                us = (time.perf_counter() - t0) * 1e6
+                if rec is not None and rec.res.dispatch_hist is not None:
+                    rec.res.dispatch_hist.record(us)
+                ov.dispatch_hist.record(us)
+            n_out = len(entry.lowered.graph.output_ids)
+            leaves = list(out) if n_out > 1 else [out]
+            return jax.tree_util.tree_unflatten(entry.lowered.out_tree,
+                                                leaves)
 
 
 class Overlay:

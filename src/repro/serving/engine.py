@@ -61,6 +61,10 @@ from repro.core.fleet import FleetOverlay
 from repro.core.overlay import Overlay
 from repro.models import model as mdl
 
+# a named span on the profiler's clock: with no profile recording, one
+# enter/exit and nothing else
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class Request:
@@ -231,11 +235,12 @@ class ServeEngine:
                 f"needs len(prompt) + 1 <= max_len; got {n + 1})")
 
     def _admit(self) -> None:
-        for slot in range(self.batch):
-            if self.slot_req[slot] is not None or not self.queue:
-                continue
-            req = self.queue.popleft()
-            self._prefill_slot(slot, req)
+        with _span("engine.admit"):
+            for slot in range(self.batch):
+                if self.slot_req[slot] is not None or not self.queue:
+                    continue
+                req = self.queue.popleft()
+                self._prefill_slot(slot, req)
 
     def _prefill_slot(self, slot: int, req: Request) -> None:
         """Prefill a single slot: run the prompt with a batch-1 cache, then
@@ -245,7 +250,8 @@ class ServeEngine:
         prompt = jnp.asarray(req.prompt, jnp.int32)[None]
         c1 = mdl.init_cache(cfg, 1, self.max_len)
         logits, c1 = self._prefill(self.params, prompt, c1)
-        self._install_stripe(slot, req, c1, int(jnp.argmax(logits[0])))
+        with _span("engine.install_stripe"):
+            self._install_stripe(slot, req, c1, int(jnp.argmax(logits[0])))
 
     def _install_stripe(self, slot: int, req: Request, c1: dict,
                         tok: int) -> None:
@@ -274,35 +280,40 @@ class ServeEngine:
     # -- decode --------------------------------------------------------------
     def step(self) -> list[Request]:
         """One engine tick: admit, batched-decode, retire. Returns finished."""
-        self._admit()
-        live = [s for s, r in enumerate(self.slot_req) if r is not None]
-        if not live:
-            return []
-        return self._decode_tick(live)
+        with _span("engine.step"):
+            self._admit()
+            live = [s for s, r in enumerate(self.slot_req) if r is not None]
+            if not live:
+                return []
+            return self._decode_tick(live)
 
     def _decode_tick(self, live: list[int]) -> list[Request]:
         """Batched ragged decode over ``live`` slots with ONE host transfer:
         sample/advance happens fused on device and the host reads a single
         packed (token, position) array per tick."""
-        logits, self.caches = self._decode(
-            self.params, self.cur_tokens, self.caches, self.slot_pos)
-        self.cur_tokens, self.slot_pos, packed = _fused_tick_update(
-            logits, self.cur_tokens, self.slot_pos, self._live_mask)
-        toks, poss = jax.device_get(packed)     # the tick's one device->host
+        with _span("engine.decode"):
+            logits, self.caches = self._decode(
+                self.params, self.cur_tokens, self.caches, self.slot_pos)
+        with _span("engine.sample"):
+            self.cur_tokens, self.slot_pos, packed = _fused_tick_update(
+                logits, self.cur_tokens, self.slot_pos, self._live_mask)
+        with _span("engine.device_get"):
+            toks, poss = jax.device_get(packed)  # the tick's one device->host
 
         finished: list[Request] = []
-        for slot in live:
-            req = self.slot_req[slot]
-            req.out.append(int(toks[slot]))
-            req.decode_steps += 1
-            # retire on decode steps, not len(out): out already holds the
-            # prefill-produced token, which is not a decode step — counting
-            # it finished requests one decode step early
-            if req.decode_steps >= req.max_new_tokens or \
-                    int(poss[slot]) + 1 >= self.max_len:
-                req.done = True
-                finished.append(req)
-                self._release_slot(slot)
+        with _span("engine.retire"):
+            for slot in live:
+                req = self.slot_req[slot]
+                req.out.append(int(toks[slot]))
+                req.decode_steps += 1
+                # retire on decode steps, not len(out): out already holds
+                # the prefill-produced token, which is not a decode step —
+                # counting it finished requests one decode step early
+                if req.decode_steps >= req.max_new_tokens or \
+                        int(poss[slot]) + 1 >= self.max_len:
+                    req.done = True
+                    finished.append(req)
+                    self._release_slot(slot)
         return finished
 
     def _release_slot(self, slot: int) -> None:

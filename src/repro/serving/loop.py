@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import model as mdl
-from repro.serving.engine import Request, ServeEngine
+from repro.serving.engine import Request, ServeEngine, _span
 from repro.serving.metrics import Histogram
 
 
@@ -79,6 +79,8 @@ class EventLoopEngine(ServeEngine):
         self.tick_hist = Histogram()         # whole-tick latency, us
         self.ttft_hist = Histogram()         # submit -> first token, us
         self.queue_delay_hist = Histogram()  # submit -> admission, us
+        self.ticks = 0                       # step() calls
+        self.prefill_ticks = 0               # of those, ticks that ran a chunk
         pc = lambda p, toks, c, li: mdl.prefill_chunk(p, cfg, toks, c, li)
         if overlay is not None:
             self._prefill_chunk = overlay.jit(
@@ -155,13 +157,14 @@ class EventLoopEngine(ServeEngine):
         return None
 
     def _admit(self) -> None:
-        for slot in range(self.batch):
-            if self.slot_req[slot] is not None:
-                continue
-            req = self._pop_admissible()
-            if req is None:
-                return
-            self._begin_prefill(slot, req)
+        with _span("engine.admit"):
+            for slot in range(self.batch):
+                if self.slot_req[slot] is not None:
+                    continue
+                req = self._pop_admissible()
+                if req is None:
+                    return
+                self._begin_prefill(slot, req)
 
     # -- chunked prefill -----------------------------------------------------
     def _begin_prefill(self, slot: int, req: Request) -> None:
@@ -182,45 +185,52 @@ class EventLoopEngine(ServeEngine):
             return self.chunk
         return 1 << (remaining - 1).bit_length()
 
-    def _prefill_tick(self) -> None:
+    def _prefill_tick(self) -> bool:
         """Advance ONE in-prefill slot by one chunk (round-robin), so no
-        single long prompt monopolizes the tick budget."""
+        single long prompt monopolizes the tick budget.  Returns whether a
+        chunk ran."""
         if not self._prefilling:
-            return
-        slots = sorted(self._prefilling)
-        slot = slots[self._pf_rr % len(slots)]
-        self._pf_rr += 1
-        st = self._prefilling[slot]
-        req, off = st["req"], st["off"]
-        n = len(req.prompt)
-        size = self._chunk_size(n - off)
-        toks = req.prompt[off:off + size]
-        last = len(toks) - 1          # last REAL token within this chunk
-        toks = toks + [0] * (size - len(toks))
-        logits, st["c1"] = self._prefill_chunk(
-            self.params, jnp.asarray(toks, jnp.int32)[None], st["c1"],
-            jnp.asarray(last, jnp.int32))
+            return False
+        with _span("engine.prefill_chunk"):
+            slots = sorted(self._prefilling)
+            slot = slots[self._pf_rr % len(slots)]
+            self._pf_rr += 1
+            st = self._prefilling[slot]
+            req, off = st["req"], st["off"]
+            n = len(req.prompt)
+            size = self._chunk_size(n - off)
+            toks = req.prompt[off:off + size]
+            last = len(toks) - 1          # last REAL token within this chunk
+            toks = toks + [0] * (size - len(toks))
+            logits, st["c1"] = self._prefill_chunk(
+                self.params, jnp.asarray(toks, jnp.int32)[None], st["c1"],
+                jnp.asarray(last, jnp.int32))
         st["off"] = off + (last + 1)
         if st["off"] >= n:
             del self._prefilling[slot]
-            self._install_stripe(slot, req, st["c1"],
-                                 int(jnp.argmax(logits[0])))
+            with _span("engine.install_stripe"):
+                self._install_stripe(slot, req, st["c1"],
+                                     int(jnp.argmax(logits[0])))
             req.first_token_time = self.clock()
             if req.submit_time is not None:
                 self.ttft_hist.record(
                     (req.first_token_time - req.submit_time) * 1e6)
+        return True
 
     # -- the event loop tick -------------------------------------------------
     def step(self) -> list[Request]:
         """One tick: admit, one prefill chunk, one fused decode, retire."""
-        t0 = time.perf_counter()
-        self._admit()
-        self._prefill_tick()
-        decoding = [s for s, r in enumerate(self.slot_req)
-                    if r is not None and s not in self._prefilling]
-        finished = self._decode_tick(decoding) if decoding else []
-        self.tick_hist.record((time.perf_counter() - t0) * 1e6)
-        return finished
+        with _span("engine.step"):
+            t0 = time.perf_counter()
+            self._admit()
+            self.ticks += 1
+            if self._prefill_tick():
+                self.prefill_ticks += 1
+            decoding = [s for s, r in enumerate(self.slot_req)
+                        if r is not None and s not in self._prefilling]
+            finished = self._decode_tick(decoding) if decoding else []
+            self.tick_hist.record((time.perf_counter() - t0) * 1e6)
+            return finished
 
     # -- observability -------------------------------------------------------
     def metrics(self) -> dict:
@@ -234,5 +244,7 @@ class EventLoopEngine(ServeEngine):
                                     if q.shed_reason == r)
                              for r in {q.shed_reason for q in self.shed}},
             "queued": len(self.queue),
+            "ticks": self.ticks,
+            "prefill_ticks": self.prefill_ticks,
             "failures": self.overlay_failures(),
         }
