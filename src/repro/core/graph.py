@@ -45,6 +45,11 @@ class NodeRef:
         return self.graph.apply(patterns.SUB, self, other)
 
 
+# name prefix of the operator that takes one element of a tuple-valued node
+# (``core/trace.py`` makes one per result of a multi-result residue)
+PROJ_PREFIX = "proj["
+
+
 @dataclasses.dataclass
 class Node:
     node_id: int
@@ -54,6 +59,11 @@ class Node:
     name: str                      # display / placement name
     aval: Any = None               # jax.ShapeDtypeStruct, filled by infer_shapes
     payload: Any = None            # const value for kind == "const"
+
+
+def is_projection(node: Node) -> bool:
+    """Whether ``node`` takes one element of its producer's tuple result."""
+    return node.kind == "op" and node.op.name.startswith(PROJ_PREFIX)
 
 
 class Graph:

@@ -52,10 +52,9 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.graph import Graph
+from repro.core.graph import Graph, is_projection
 from repro.core.isa import Opcode, Program, compile_graph
 from repro.core.placement import Placement
-from repro.core.trace import PROJ_PREFIX
 
 
 # --------------------------------------------------------------------------
@@ -273,9 +272,12 @@ def build_kernel(graph: Graph, *,
     placement (their routes entries are always 0).  Routing them anyway
     would push every weight through a loop carry, and XLA copies a
     read-only argument into each carry: at published widths that is one
-    extra copy of the model's weights per call.  An edge into a projection
-    moves only the element projected, not the whole tuple-valued residue
-    (selection commutes with copy passes).
+    extra copy of the model's weights per call.  A projection sits on its
+    producer's tile (both placement policies put it there), so the edge
+    into it is local too and takes its element with no loop: even a
+    zero-trip loop pins its operand's layout, and on a tuple-valued
+    residue such as the layer scan that forced a relayout copy of the
+    whole KV cache.  The projection's out-edges are routed as usual.
     """
     nodes = graph.toposorted()
     eidx = {e: i for i, e in enumerate(edge_order(graph))}
@@ -290,11 +292,11 @@ def build_kernel(graph: Graph, *,
             if n.kind == "const":
                 vals[n.node_id] = n.payload
                 continue
-            route = lambda src: routes[eidx[(src, n.node_id)]]
-            if n.kind == "op" and n.op.name.startswith(PROJ_PREFIX):
+            if is_projection(n):
                 (src,) = n.inputs                # a multi-result residue
-                vals[n.node_id] = hop(n.op.fn(vals[src]), route(src))
+                vals[n.node_id] = n.op.fn(vals[src])
                 continue
+            route = lambda src: routes[eidx[(src, n.node_id)]]
             args = [vals[src] if src in unplaced else hop(vals[src], route(src))
                     for src in n.inputs]
             if n.kind == "op":
@@ -413,8 +415,10 @@ def specialize_kernel(graph: Graph, hops: "tuple[int, ...]", *,
         raise ValueError(
             f"hop vector has {len(hops)} entries for {len(order)} edges")
     static_hops = {e: int(h) for e, h in zip(order, hops)}
-    # graph inputs and constants are never fused with a producer
-    guards = {e: by_id[e[0]].kind not in ("input", "const") for e in order}
+    # graph inputs and constants are never fused with a producer, and a
+    # projection's in-edge is local in the generic tier too
+    guards = {e: by_id[e[0]].kind not in ("input", "const")
+              and not is_projection(by_id[e[1]]) for e in order}
     needs_one = any(g or static_hops[e] >= 2 for e, g in guards.items())
     factory = hop_factory or _static_barrier_hops
 
@@ -426,6 +430,10 @@ def specialize_kernel(graph: Graph, hops: "tuple[int, ...]", *,
                 continue
             if n.kind == "const":
                 vals[n.node_id] = n.payload
+                continue
+            if is_projection(n):
+                (src,) = n.inputs
+                vals[n.node_id] = n.op.fn(vals[src])
                 continue
             args = []
             for src in n.inputs:

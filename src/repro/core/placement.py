@@ -28,7 +28,7 @@ import enum
 import itertools
 from typing import Iterable
 
-from repro.core.graph import Graph, Node
+from repro.core.graph import Graph, Node, is_projection
 from repro.core.patterns import TileClass
 
 
@@ -160,6 +160,15 @@ def _class_ok(node: Node, coord: Coord, grid: TileGrid) -> bool:
     return True  # SMALL ops may sit on either tile size (paper packs both)
 
 
+def _producer_tile(node: Node, assignment: dict[int, Coord]) -> Coord | None:
+    """The tile a projection must take: its producer's, where the tuple it
+    takes an element of is made.  ``None`` for any other node.  So the edge
+    into a projection has 0 hops at every placement and claims no tile."""
+    if is_projection(node) and node.inputs[0] in assignment:
+        return assignment[node.inputs[0]]
+    return None
+
+
 def _edge_costs(graph: Graph, assignment: dict[int, Coord]) -> dict[tuple[int, int], int]:
     """Per-dataflow-edge Manhattan hop counts under an assignment."""
     hops: dict[tuple[int, int], int] = {}
@@ -188,12 +197,22 @@ def place_static(graph: Graph, grid: TileGrid,
     regime the paper's static overlay suffers from, packed incrementally
     around whatever is already resident.  ``max_tiles`` caps the footprint
     (the round-robin pool) so one accelerator cannot monopolize the fabric.
+    Under either rule a projection takes its producer's tile and needs no
+    pin (a pin elsewhere is a :class:`PlacementError`).
     """
     occupied = set(occupied)
     ops = graph.op_nodes()
     assignment: dict[int, Coord] = {}
     if fixed is not None:
         for node in ops:
+            tile = _producer_tile(node, assignment)
+            if tile is not None:
+                if fixed.get(node.node_id, tile) != tile:
+                    raise PlacementError(
+                        f"projection {node.name!r} pinned to tile "
+                        f"{fixed[node.node_id]}, off its producer's {tile}")
+                assignment[node.node_id] = tile
+                continue
             if node.node_id not in fixed:
                 raise PlacementError(f"static placement missing node {node.node_id}")
             coord = fixed[node.node_id]
@@ -219,6 +238,10 @@ def place_static(graph: Graph, grid: TileGrid,
         large_pool = itertools.cycle(free_large or window)
         all_pool = itertools.cycle(window)
         for node in ops:
+            tile = _producer_tile(node, assignment)
+            if tile is not None:
+                assignment[node.node_id] = tile
+                continue
             cls = node.op.tile_class if node.op is not None else TileClass.SMALL
             if cls is TileClass.LARGE and not free_large and grid.large_coords():
                 # grid has LARGE tiles but none are free: residency pressure
@@ -255,6 +278,7 @@ def place_dynamic(graph: Graph, grid: TileGrid, *,
     does not monopolize the fabric; the cap is soft — it is exceeded only
     when a class-incompatible footprint would otherwise fail (e.g. the
     first LARGE op of a budget-exhausted graph still claims a LARGE tile).
+    A projection takes its producer's tile and claims none of its own.
     """
     occupied = set(occupied)
     ops = graph.op_nodes()
@@ -263,6 +287,10 @@ def place_dynamic(graph: Graph, grid: TileGrid, *,
     used: set[Coord] = set()
 
     for node in ops:
+        tile = _producer_tile(node, assignment)
+        if tile is not None:
+            assignment[node.node_id] = tile
+            continue
         producers = [assignment[i] for i in node.inputs if i in assignment]
         cand_all = [c for c in free if _class_ok(node, c, grid)]
         cls = node.op.tile_class if node.op is not None else TileClass.SMALL
@@ -311,7 +339,8 @@ def check_assignment(graph: Graph, grid: TileGrid,
                      placement: Placement) -> None:
     """Validate a (possibly hand-built) placement against the invariants
     ``place()`` guarantees: every op node assigned, coordinates on the grid,
-    and LARGE ops only on LARGE tiles.  Raises :class:`PlacementError` —
+    LARGE ops only on LARGE tiles, and each projection on its producer's
+    tile.  Raises :class:`PlacementError` —
     the guard for placements entering the fabric from outside the placer
     (e.g. ``Overlay.relocate``)."""
     nodes = {n.node_id: n for n in graph.toposorted()}
@@ -331,6 +360,12 @@ def check_assignment(graph: Graph, grid: TileGrid,
     if missing:
         raise PlacementError(
             f"assignment missing op nodes {missing[:5]}")
+    for node in graph.op_nodes():
+        tile = _producer_tile(node, placement.assignment)
+        if tile is not None and placement.assignment[node.node_id] != tile:
+            raise PlacementError(
+                f"projection {node.name!r} assigned off its producer's "
+                f"tile {tile}")
 
 
 # -- cost-model planning (DESIGN.md §11) -------------------------------------

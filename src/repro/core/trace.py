@@ -41,11 +41,10 @@ import jax
 
 from repro.compat import ClosedJaxpr, DropVar, Jaxpr, Literal
 from repro.core import patterns
-from repro.core.graph import Graph, NodeRef
+from repro.core.graph import PROJ_PREFIX, Graph, NodeRef
 from repro.core.patterns import Operator, TileClass
 
 RESIDUE_PREFIX = "xla["
-PROJ_PREFIX = "proj["
 
 # call-style primitives whose sub-jaxpr we inline (NOT loop/branch primitives
 # like scan/while/cond, whose sub-jaxprs have different calling conventions —

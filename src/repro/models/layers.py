@@ -113,6 +113,38 @@ def cache_update(cache: jax.Array, new: jax.Array, idx, *, axis: int):
         cache, new.astype(cache.dtype), idx, axis=axis)
 
 
+# rows are written through a window of this many positions, aligned to it:
+# one (8, 128) tile row of the cache's sequence axis, so the write leaves
+# the cache in its default device layout
+KV_WRITE_WINDOW = 128
+
+
+def write_rows(cache: jax.Array, layer, new: jax.Array,
+               pos: jax.Array) -> jax.Array:
+    """Write each batch row's new K or V entry into one layer of a stacked
+    cache, in place: ``cache[layer, b, :, pos[b], :] = new[b, :, 0, :]``.
+
+    cache: (L, B, Hkv, Smax, hd); new: (B, Hkv, 1, hd); pos: (B,) int32.
+    Each row reads an aligned window of ``KV_WRITE_WINDOW`` positions,
+    selects its one entry into it and writes the window back, so the write
+    moves B windows rather than the layer's whole slice.  A position
+    outside [0, Smax) writes nothing, as a one-hot select would.
+    """
+    _, b, hkv, smax, hd = cache.shape
+    w = min(KV_WRITE_WINDOW, smax)
+    new = new.astype(cache.dtype)
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    for r in range(b):
+        start = jnp.clip(pos[r] // w * w, 0, smax - w)
+        at = (layer, jnp.int32(r), zero, start, zero)
+        win = jax.lax.dynamic_slice(cache, at, (1, 1, hkv, w, hd))
+        sel = (jnp.arange(w) == pos[r] - start)[:, None]
+        win = jnp.where(sel, new[r][None, None], win)
+        cache = jax.lax.dynamic_update_slice(cache, win, at)
+    return cache
+
+
 # ---------------------------------------------------------------------------
 # Attention (GQA family)
 # ---------------------------------------------------------------------------
@@ -257,13 +289,18 @@ def multihead_attention(q, k, v, *, causal=True, window=None, softcap=None,
 
 def attn_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *, kind: str,
              positions: jax.Array, cache: dict | None = None,
-             x_kv: jax.Array | None = None) -> tuple[jax.Array, dict | None]:
+             x_kv: jax.Array | None = None,
+             layer: jax.Array | int | None = None
+             ) -> tuple[jax.Array, dict | None]:
     """Unified attention forward.
 
     x: (B, S, D). kind: dense|local|global|shared_attn|enc|cross.
     cache: None (train/prefill without cache) or
       {"k": (B, Hkv, Smax, hd), "v": ..., "index": scalar} for decode.
     x_kv: encoder output for cross attention.
+    layer: ragged decode only — the cache is the layer-stacked
+      {"k": (L, B, Hkv, Smax, hd), "v": ..., "index": (L,)} and this call
+      is layer ``layer`` of it; the returned cache is the whole stack.
     Returns (out, updated_cache).
     """
     b, s, _ = x.shape
@@ -314,22 +351,26 @@ def attn_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *, kind: str,
         elif getattr(positions, "ndim", 0) >= 2:
             # ragged decode (s == 1): every batch row writes its KV entry at
             # its OWN position and attends against its own filled extent.
-            # One-hot jnp.where writes (pure value copies, batch/head-local;
-            # seq stays unsharded under SERVE_RULES so this is shard-local)
-            # instead of a shared dynamic_update_slice — the scalar cache
+            # With ``layer`` given the cache leaves are the layer-stacked
+            # arrays the scan carries, written in place; the scalar cache
             # "index" keeps ticking but the mask below never reads it.
             pos_b = positions[:, 0].astype(jnp.int32)              # (B,)
-            sel = jnp.arange(cache["k"].shape[2])[None, :] == pos_b[:, None]
-            ck = jnp.where(sel[:, None, :, None],
-                           kt.astype(cache["k"].dtype), cache["k"])
-            cv = jnp.where(sel[:, None, :, None],
-                           vt.astype(cache["v"].dtype), cache["v"])
+            ks, vs, lyr = cache["k"], cache["v"], layer
+            if layer is None:        # one layer's own cache: a stack of one
+                ks, vs, lyr = ks[None], vs[None], 0
+            ks = write_rows(ks, lyr, kt, pos_b)
+            vs = write_rows(vs, lyr, vt, pos_b)
+            ck = jax.lax.dynamic_index_in_dim(ks, lyr, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(vs, lyr, keepdims=False)
             ck = shd.constrain_logical(ck, ("batch", "kv_heads", "seq", None))
             cv = shd.constrain_logical(cv, ("batch", "kv_heads", "seq", None))
             o = _attention_xla(qt, ck, cv, causal=True, window=window,
                                softcap=cfg.attn_softcap, scale=scale,
                                q_offset=pos_b, kv_len=pos_b + s)
-            new_cache = {"k": ck, "v": cv, "index": idx + s}
+            if layer is None:
+                new_cache = {"k": ck, "v": cv, "index": idx + s}
+            else:
+                new_cache = {"k": ks, "v": vs, "index": idx.at[layer].add(s)}
         else:
             ck = cache_update(cache["k"], kt, idx, axis=2)
             cv = cache_update(cache["v"], vt, idx, axis=2)

@@ -131,8 +131,9 @@ def _maybe_post(cfg, p, key, x):
 
 
 def layer_fwd(p: dict, x: jax.Array, kind: str, cfg: ArchConfig, *,
-              positions: jax.Array, cache=None, enc_out=None):
-    """One layer. Returns (x, new_cache, aux_loss)."""
+              positions: jax.Array, cache=None, enc_out=None, layer=None):
+    """One layer. Returns (x, new_cache, aux_loss).  ``layer`` marks a
+    layer-stacked self-attention cache (see :func:`attn_fwd`)."""
     aux = jnp.zeros((), jnp.float32)
     rs = cfg.residual_scale
     if kind == "mamba":
@@ -147,7 +148,8 @@ def layer_fwd(p: dict, x: jax.Array, kind: str, cfg: ArchConfig, *,
     else:
         self_c = cache["self"] if (kind == "dec" and cache is not None) else cache
         h, self_cache = attn_fwd(p["attn"], h, cfg, kind=kind,
-                                 positions=positions, cache=self_c)
+                                 positions=positions, cache=self_c,
+                                 layer=layer)
     h = _maybe_post(cfg, p, "post_ln1", h)
     x = x + rs * h
 
@@ -178,23 +180,40 @@ def layer_fwd(p: dict, x: jax.Array, kind: str, cfg: ArchConfig, *,
 
 def group_fwd(gp: dict, x: jax.Array, unit: tuple[str, ...], rep: int,
               cfg: ArchConfig, *, positions, caches=None, enc_out=None):
-    """Scan ``rep`` repetitions of ``unit``. Returns (x, new_caches, aux)."""
-    shared = gp.get("shared", {})
+    """Scan ``rep`` repetitions of ``unit``. Returns (x, new_caches, aux).
 
-    def body(x, xs):
-        layer_p, cache_sl = xs
+    In the ragged decode (2-D ``positions``) every self-attention K/V cache
+    rides in the scan carry as its layer-stacked arrays, and each layer
+    writes its rows into it in place; every other cache is scanned as
+    ``xs`` and comes back as ``ys``.
+    """
+    shared = gp.get("shared", {})
+    ragged = caches is not None and getattr(positions, "ndim", 0) >= 2
+    kv = {k: c for k, c in (caches or {}).items() if ragged and "k" in c}
+    scanned = (None if caches is None else
+               {k: c for k, c in caches.items() if k not in kv})
+
+    def body(carry, xs):
+        x, kv = carry
+        layer_p, cache_sl, layer = xs
+        kv = dict(kv)
         aux_total = jnp.zeros((), jnp.float32)
         new_cache_sl = {} if cache_sl is not None else None
         for i, kind in enumerate(unit):
             key = f"{i}:{kind}"
             p = shared["shared_attn"] if kind == "shared_attn" else layer_p[key]
-            c = cache_sl[key] if cache_sl is not None else None
-            x, nc, aux = layer_fwd(p, x, kind, cfg, positions=positions,
-                                   cache=c, enc_out=enc_out)
-            if new_cache_sl is not None:
-                new_cache_sl[key] = nc
+            if key in kv:
+                x, kv[key], aux = layer_fwd(p, x, kind, cfg,
+                                            positions=positions,
+                                            cache=kv[key], layer=layer)
+            else:
+                c = cache_sl[key] if cache_sl is not None else None
+                x, nc, aux = layer_fwd(p, x, kind, cfg, positions=positions,
+                                       cache=c, enc_out=enc_out)
+                if new_cache_sl is not None:
+                    new_cache_sl[key] = nc
             aux_total += aux
-        return x, (new_cache_sl, aux_total)
+        return (x, kv), (new_cache_sl, aux_total)
 
     if cfg.remat == "full":
         body = jax.checkpoint(body, prevent_cse=False)
@@ -207,16 +226,20 @@ def group_fwd(gp: dict, x: jax.Array, unit: tuple[str, ...], rep: int,
         new_caches, auxs = [], []
         for r in range(rep):
             lp = jax.tree.map(lambda a: a[r], gp["layers"])
-            cs = (jax.tree.map(lambda a: a[r], caches)
-                  if caches is not None else None)
-            x, (nc, aux) = body(x, (lp, cs))
+            cs = (jax.tree.map(lambda a: a[r], scanned)
+                  if scanned is not None else None)
+            (x, kv), (nc, aux) = body((x, kv), (lp, cs, r if kv else None))
             new_caches.append(nc)
             auxs.append(aux)
-        nc_stack = (jax.tree.map(lambda *a: jnp.stack(a), *new_caches)
-                    if caches is not None else None)
-        return x, nc_stack, jnp.sum(jnp.stack(auxs))
-
-    x, (new_caches, auxs) = jax.lax.scan(body, x, (gp["layers"], caches))
+        new_caches = (jax.tree.map(lambda *a: jnp.stack(a), *new_caches)
+                      if caches is not None else None)
+        auxs = jnp.stack(auxs)
+    else:
+        layers = jnp.arange(rep, dtype=jnp.int32) if kv else None
+        (x, kv), (new_caches, auxs) = jax.lax.scan(
+            body, (x, kv), (gp["layers"], scanned, layers))
+    if caches is not None:
+        new_caches = {**new_caches, **kv}
     return x, new_caches, jnp.sum(auxs)
 
 

@@ -110,7 +110,8 @@ class ServeEngine:
         self.slot_req: list[Request | None] = [None] * batch
         self.slot_pos = jnp.zeros((batch,), jnp.int32)
         self.queue: collections.deque[Request] = collections.deque()
-        # ragged decode: every slot decodes at its own KV position
+        # ragged decode: every slot decodes at its own KV position, writing
+        # its new K/V into the cache it donates (argument 2) in place
         step = lambda p, t, c, pos: mdl.decode_step(p, cfg, t, c,
                                                     positions=pos)
         pf = lambda p, toks, c: mdl.prefill(p, cfg, toks, c)
@@ -120,17 +121,21 @@ class ServeEngine:
             self.tile_budget = tile_budget
             self._decode = overlay.jit(step, strict=False,
                                        name=f"{cfg.name}.decode",
-                                       tile_budget=tile_budget)
+                                       tile_budget=tile_budget,
+                                       donate_argnums=(2,))
             self._prefill = overlay.jit(pf, strict=False,
                                         name=f"{cfg.name}.prefill",
                                         tile_budget=tile_budget)
         else:
             self.tile_budget = tile_budget
-            self._decode = jax.jit(step)
+            self._decode = jax.jit(step, donate_argnums=2)
             self._prefill = jax.jit(pf)
         self.cur_tokens = jnp.zeros((batch, 1), jnp.int32)
         self._live_mask = jnp.zeros((batch,), jnp.int32)
         self._decode_prefetched = False
+        # decode ticks whose input cache the step consumed (donated): every
+        # tick once the decode executable is downloaded
+        self.kv_donated_ticks = 0
 
     # -- fabric management (relocatable bitstreams, DESIGN.md §6) ------------
     def compact(self) -> int:
@@ -291,9 +296,11 @@ class ServeEngine:
         """Batched ragged decode over ``live`` slots with ONE host transfer:
         sample/advance happens fused on device and the host reads a single
         packed (token, position) array per tick."""
+        probe = jax.tree.leaves(self.caches)[0]
         with _span("engine.decode"):
             logits, self.caches = self._decode(
                 self.params, self.cur_tokens, self.caches, self.slot_pos)
+        self.kv_donated_ticks += probe.is_deleted()
         with _span("engine.sample"):
             self.cur_tokens, self.slot_pos, packed = _fused_tick_update(
                 logits, self.cur_tokens, self.slot_pos, self._live_mask)

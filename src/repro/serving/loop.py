@@ -246,5 +246,6 @@ class EventLoopEngine(ServeEngine):
             "queued": len(self.queue),
             "ticks": self.ticks,
             "prefill_ticks": self.prefill_ticks,
+            "kv_donated_ticks": self.kv_donated_ticks,
             "failures": self.overlay_failures(),
         }

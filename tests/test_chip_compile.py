@@ -6,22 +6,32 @@ main path at real widths with ``interpret=False`` against the v5e topology
 description and check that the compiled HLO holds the Mosaic kernel
 (``tpu_custom_call``).  Nothing runs; a compile takes a second or two.
 
+The served decode step is compiled the same way, as the overlay's generic
+tier assembles it (about ten seconds each): its KV cache must be updated in
+place, with no relayout copy of the cache or of one layer's slice of it.
+
 The topology is described inside a module-scoped fixture, never at import,
 so every pytest-xdist worker collects the same tests and only the worker
 that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core import build_kernel, trace_to_graph
 from repro.kernels import flash_attention as fa
 from repro.kernels import rmsnorm as rn
 from repro.kernels import ssd_scan
 from repro.kernels import vmul_reduce as vr
+from repro.models import model as mdl
+from repro.models import params as pm
+from repro.models.transformer import model_spec
 
 
 @pytest.fixture(scope="module")
@@ -84,3 +94,47 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     fn, args = _kernel_case(name, sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the served cells' widths at two layers: (arch, batch); max_len 1024
+DECODE_CASES = [("phi3-mini-3.8b", 4), ("minicpm-2b", 6)]
+
+
+def _copied_shapes(hlo: str) -> set[tuple[int, ...]]:
+    """The shapes every ``copy``/``copy-start`` of the module produces."""
+    shapes = set()
+    for line in hlo.splitlines():
+        m = re.search(r"= (.*?) (copy|copy-start)\(", line)
+        if m:
+            for dims in re.findall(r"\[([\d,]*)\]", m.group(1)):
+                shapes.add(tuple(int(d) for d in dims.split(",") if d))
+    return shapes
+
+
+@pytest.mark.parametrize("arch,batch", DECODE_CASES)
+def test_decode_updates_cache_in_place_for_v5e(arch, batch, one_chip,
+                                               no_compile_cache):
+    layers, max_len = 2, 1024
+    cfg = get_config(arch).scaled(blocks=((("dense",), layers),))
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, pm.abstract(model_spec(cfg)))
+    caches = jax.tree.map(sds, jax.eval_shape(
+        lambda: mdl.init_cache(cfg, batch, max_len)))
+    tok = sds(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    pos = sds(jax.ShapeDtypeStruct((batch,), jnp.int32))
+    step = lambda p, t, c, q: mdl.decode_step(p, cfg, t, c, positions=q)
+    graph = trace_to_graph(step, params, tok, caches, pos,
+                           name=f"{cfg.name}.decode").graph
+    routes = sds(jax.ShapeDtypeStruct((len(graph.edges()),), jnp.int32))
+    n_params, n_cache = len(jax.tree.leaves(params)), len(jax.tree.leaves(caches))
+    first = 1 + n_params + 1                    # after routes, params, tokens
+    donate = tuple(range(first, first + n_cache))
+    compiled = jax.jit(build_kernel(graph), donate_argnums=donate).lower(
+        routes, *jax.tree.leaves((params, tok, caches, pos))).compile()
+
+    kv = (batch, cfg.num_kv_heads, max_len, cfg.resolved_head_dim)
+    assert not _copied_shapes(compiled.as_text()) & {
+        (layers, *kv), (1, *kv), kv}
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
