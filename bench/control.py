@@ -7,9 +7,9 @@
 For each seed, in one process: the cell's set-up and a window of its own
 traffic, as in a benchmark run; then the reference over the sample that a
 run compares, once for what the program served and once for the control,
-the same reference computed in float8 (``reference.py``).  Prints, per
-seed, the widest gap of each (the program's is the lower reading, the
-control's the upper) and a JSON summary as the last line.  With
+the same reference computed in float8 (the family's ``served_gaps``).
+Prints, per seed, the widest gap of each (the program's is the lower
+reading, the control's the upper) and a JSON summary as the last line.  With
 ``--fault``, one of ``faults.FAULTS`` breaks the timed path and the
 program's gap and ``correct`` are those of the broken path.  Not part of a
 benchmark run.

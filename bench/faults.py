@@ -6,6 +6,7 @@ The harness's own tests run them at a tiny width on the CPU, and
 No benchmark run installs one.
 """
 
+import jax
 import jax.numpy as jnp
 
 
@@ -22,13 +23,16 @@ def alter_token(eng):
 
 
 def state_unchanged(eng):
-    """A step that returns its state unchanged: decode hands back the cache
-    it was given, so no decoded token's keys and values are kept."""
+    """A step that returns its state unchanged: decode hands back a copy of
+    the cache it was given, so no decoded token's keys and values are kept.
+    Decode consumes (donates) the cache it is passed, so the copy is taken
+    before the call."""
     decode = eng._decode
 
     def broken(params, toks, caches, pos):
+        kept = jax.tree.map(jnp.copy, caches)
         logits, _ = decode(params, toks, caches, pos)
-        return logits, caches
+        return logits, kept
     eng._decode = broken
 
 

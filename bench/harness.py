@@ -1,11 +1,13 @@
 """One run of one benchmark cell: set-up, a timed window, the check.
 
 Everything that belongs to a cell is found by name: the cell in
-``BENCHMARK.json`` names a configuration (``bench/configs/<config>.json``)
-and a traffic mix (``bench/traffic/<mix>.json``, whose arrival process is a
-module of ``bench/arrivals/``); each per-layer metric is read by
-``bench/metrics/<name before the first dot>.py``.  Adding a cell, a mix or a
-metric adds files and entries and edits none.
+``BENCHMARK.json`` names a configuration (``bench/configs/<config>.json``,
+whose layer family is a module of ``bench/families/``) and a traffic mix
+(``bench/traffic/<mix>.json``, whose arrival process is a module of
+``bench/arrivals/``); each per-layer metric is read by
+``bench/metrics/<name before the first dot>.py``.  Adding a cell, a mix, a
+metric or a configuration of a new family adds files and entries and edits
+none.
 
 The window drives ``EventLoopEngine.step()`` over an ``Overlay`` exactly as
 a server would; the generator runs in the same process and, before every
@@ -31,8 +33,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import traffic
-from bench.weights import Dims
+from bench import families, traffic
 
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
@@ -200,26 +201,6 @@ def _device_check(jax, chips: int, require_chip: bool):
     return dev, devs
 
 
-def _program_config(cell: Cell, dims: Dims):
-    """The program's registered config of the arch, run at the benchmark's
-    widths and equations: it has to be a dense decoder with the published
-    activation, and every number the benchmark states replaces the
-    program's own."""
-    from repro.configs import get_config
-    cfg = get_config(cell.config["arch"])
-    act = cell.config["published"]["hidden_act"]
-    if {k for u, _ in cfg.blocks for k in u} != {"dense"} or cfg.act != act:
-        raise BenchError(f"program config {cfg.name} is not a dense "
-                         f"decoder with {act}")
-    return cfg.scaled(
-        d_model=dims.d_model, num_heads=dims.heads,
-        num_kv_heads=dims.kv_heads, head_dim=dims.head_dim, d_ff=dims.d_ff,
-        vocab_size=dims.vocab, blocks=((("dense",), dims.layers),),
-        tie_embeddings=dims.tied, embed_scale=dims.embed_scale,
-        residual_scale=dims.residual_scale, norm_eps=dims.norm_eps,
-        rope_theta=dims.rope_theta, dtype=dims.dtype)
-
-
 def _warm_prompt_lens(lo: int, hi: int, chunk: int) -> list[int]:
     """One prompt per prefill bucket that prompts of ``lo..hi`` tokens use:
     a bucket ``b <= chunk`` is a prompt of ``b`` tokens (one chunk)."""
@@ -251,7 +232,8 @@ def _sample(done: list, seed: int) -> list:
 
 class Bench:
     """The process-wide part of set-up: the device, the compile cache, the
-    program's modules, and a count of programs compiled in the window."""
+    configuration's family and the program's modules, and a count of
+    programs compiled in the window."""
 
     def __init__(self, cell: Cell, *, require_chip: bool = True):
         import jax
@@ -277,8 +259,9 @@ class Bench:
 
         jax.monitoring.register_event_duration_secs_listener(on_duration)
         jax.monitoring.register_event_listener(on_event)
-        self.dims = Dims.from_config(cell.config)
-        self.cfg = _program_config(cell, self.dims)
+        self.family = families.of(cell.config)
+        self.dims = self.family.Dims.from_config(cell.config)
+        self.cfg = self.family.program_config(cell.config, self.dims)
         eng = cell.config["engine"]
         self.batch, self.chunk = int(eng["batch"]), int(eng["chunk"])
         self.max_len = int(eng["max_len"])
@@ -291,10 +274,9 @@ class Bench:
         from repro.models.transformer import model_spec
         from repro.serving import EventLoopEngine, Request
 
-        from bench.weights import program_params, root_key
-
         t = CLOCK()
-        params = program_params(self.dims, root_key(seed))
+        params = self.family.program_params(self.dims,
+                                            families.root_key(seed))
         want = pm.abstract(model_spec(self.cfg))
         got = jax.eval_shape(lambda: params)
         if jax.tree.structure(want) != jax.tree.structure(got) or not all(
@@ -461,12 +443,11 @@ class Window:
 def check(bench: Bench, win: Window, seed: int, *, control: bool = False):
     """Compare a sample of what the window served with the reference.
     Returns (checks, correct, gaps)."""
-    from bench import reference
-
     sample = [(list(r.prompt), list(r.out))
               for r in _sample(win.done(), seed)]
     t = CLOCK()
-    gaps = reference.served_gaps(bench.dims, seed, sample, control=control) \
+    gaps = bench.family.served_gaps(bench.dims, seed, sample,
+                                    control=control) \
         if sample else {"program": np.array([np.inf])}
     widest = float(np.max(gaps["program"]))
     log(f"check: reference over {len(sample)} requests, "
@@ -553,8 +534,9 @@ def _per_layer(bench: Bench, obs: Observer, trace_dir):
         tr = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
         k = len(tr.tick_busy_s)
         traced_ticks = obs.ticks[obs.window_ticks - k:obs.window_ticks]
-    ctx = MetricContext(dims=bench.dims, config=cell.config, peaks=peaks,
-                        obs=obs, trace=tr, traced_ticks=traced_ticks)
+    ctx = MetricContext(family=bench.family, dims=bench.dims,
+                        config=cell.config, peaks=peaks, obs=obs, trace=tr,
+                        traced_ticks=traced_ticks)
     metrics = {}
     for m in cell.metrics("per_layer"):
         value = metric_reader(m["name"])(ctx)
@@ -571,7 +553,8 @@ def _per_layer(bench: Bench, obs: Observer, trace_dir):
 class MetricContext:
     """What a per-layer reader may read."""
 
-    dims: Dims
+    family: object             # the configuration's module of bench.families
+    dims: object               # family.Dims of the configuration
     config: dict
     peaks: dict
     obs: Observer

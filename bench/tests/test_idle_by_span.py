@@ -120,8 +120,8 @@ def test_recorded_trace_names_gaps_and_ops_by_program():
 
 
 def _ctx(trace=None):
-    return harness.MetricContext(dims=None, config={}, peaks={}, obs=None,
-                                 trace=trace, traced_ticks=[])
+    return harness.MetricContext(family=None, dims=None, config={}, peaks={},
+                                 obs=None, trace=trace, traced_ticks=[])
 
 
 def _program(spans, step_s=0.0, step_busy_s=0.0):

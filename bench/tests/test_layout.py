@@ -8,8 +8,7 @@ import re
 
 import pytest
 
-from bench import harness, traffic
-from bench.weights import Dims
+from bench import families, harness, traffic
 from conftest import ROOT
 
 BM = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -31,7 +30,8 @@ def test_names_and_units():
 @pytest.mark.parametrize("cell", [w["name"] for w in BM["workloads"]])
 def test_cell_resolves(cell):
     c = harness.load_cell(cell)
-    Dims.from_config(c.config)
+    fam = families.of(c.config)
+    assert fam.Dims.from_config(c.config).vocab > 0
     importlib.import_module(f"bench.arrivals.{c.mix['arrival']}")
     assert c.workload["chips"] == 1
     e2e = {m["name"] for m in c.metrics("end_to_end")}

@@ -36,19 +36,57 @@ def test_lengths_inside_clips(name):
         assert all(0 <= t < 32064 for t in r.prompt)
 
 
+def _schedule(tr):
+    return [(len(r.prompt), r.max_new_tokens, r.due) for r in tr.requests]
+
+
 @pytest.mark.parametrize("name", ["chat-bursty"])
 def test_every_seed_offers_the_same_work(name):
-    a, b = _gen(name, 1), _gen(name, 2)
+    a, b = _gen(name, 1), _gen(name, 2**31 + 11)
     assert sorted(len(r.prompt) for r in a.requests) == \
         sorted(len(r.prompt) for r in b.requests)
     assert sorted(r.max_new_tokens for r in a.requests) == \
         sorted(r.max_new_tokens for r in b.requests)
+    # the mix fixes its schedule: the same requests come at the same times
+    # for every seed, and only the token ids differ
+    assert "schedule_seed" in a.mix
+    assert _schedule(a) == _schedule(b)
+    assert [r.prompt for r in a.requests] != [r.prompt for r in b.requests]
+
+
+def test_seed_shuffles_the_order_without_a_schedule_seed():
+    mix = dict(traffic.load_mix("chat-bursty", ROOT / "bench"))
+    del mix["schedule_seed"]
+
+    def gen(seed):
+        return traffic.generate(mix, seed=seed, seconds=40.0, vocab=32064,
+                                batch=4)
+
+    a, b = gen(1), gen(2)
+    assert sorted(len(r.prompt) for r in a.requests) == \
+        sorted(len(r.prompt) for r in b.requests)
     # the due times show all gaps but the last: the two seeds share all of
     # them but at most one, in another order
     ga = np.round(np.diff([r.due for r in a.requests]), 9)
     gb = np.round(np.diff([r.due for r in b.requests]), 9)
     assert list(ga) != list(gb)
     assert len(set(ga) & set(gb)) >= len(ga) - 1
+
+
+def test_schedule_seed_draws_the_order_and_not_the_ids():
+    """With ``schedule_seed`` the schedule is the one that seed would shuffle
+    without the key; the ids are drawn from the run's seed."""
+    mix = traffic.load_mix("chat-bursty", ROOT / "bench")
+    free = {k: v for k, v in mix.items() if k != "schedule_seed"}
+    fixed = traffic.generate(mix, seed=2**31 + 3, seconds=40.0, vocab=32064,
+                             batch=4)
+    shuffled = traffic.generate(free, seed=mix["schedule_seed"],
+                                seconds=40.0, vocab=32064, batch=4)
+    assert _schedule(fixed) == _schedule(shuffled)
+    again = traffic.generate(mix, seed=2**31 + 3, seconds=40.0, vocab=32064,
+                             batch=4)
+    assert [r.prompt for r in fixed.requests] == \
+        [r.prompt for r in again.requests]
 
 
 @pytest.mark.parametrize("name", ["chat-bursty"])

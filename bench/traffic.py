@@ -7,6 +7,13 @@ seed offers the same multiset of sizes and arrivals, in another order, so
 the seed changes which request comes when and never how much work there
 is.  Prompt token ids are drawn from the seed.
 
+A mix may fix its schedule with ``"schedule_seed": <n>``: the order of
+lengths and gaps, and so which request comes when, is then drawn from
+``n`` and is the same for every seed, which draws only the token ids.
+Where a tail sits near the edge between two kinds of tick, the order
+alone decides which side it reads (a bursty mix's share of gaps stretched
+by a prefill chunk moves with it), so such a mix fixes its order.
+
 Length distributions (``prompt`` and ``output``):
 
 * ``{"dist": "uniform", "min": a, "max": b}`` -- integers ``a..b``;
@@ -76,13 +83,15 @@ def quantile_lengths(dist: dict, n: int) -> np.ndarray:
 def generate(mix: dict, *, seed: int, seconds: float, vocab: int,
              batch: int) -> Traffic:
     rng = np.random.default_rng(seed)
+    order = rng if "schedule_seed" not in mix else \
+        np.random.default_rng(int(mix["schedule_seed"]))
     mod = arrival_module(mix)
     if mod.CLOSED:
         n = int(mix["pool"])
     else:
         n = max(1, int(round(float(mix["rate_rps"]) * seconds)))
-    prompts = rng.permutation(quantile_lengths(mix["prompt"], n))
-    outputs = rng.permutation(quantile_lengths(mix["output"], n))
+    prompts = order.permutation(quantile_lengths(mix["prompt"], n))
+    outputs = order.permutation(quantile_lengths(mix["output"], n))
     if mod.CLOSED:
         due = [None] * n
         cuts = mix.get("first_wave_cut")
@@ -90,7 +99,7 @@ def generate(mix: dict, *, seed: int, seconds: float, vocab: int,
             for i in range(min(batch, n)):
                 outputs[i] = max(1, int(outputs[i] * cuts[i % len(cuts)]))
     else:
-        gaps = rng.permutation(np.asarray(mod.gaps(mix, n), np.float64))
+        gaps = order.permutation(np.asarray(mod.gaps(mix, n), np.float64))
         gaps *= seconds / gaps.sum()
         due = np.concatenate([[0.0], np.cumsum(gaps)[:-1]]).tolist()
     reqs = [Offered(rid=i,
