@@ -60,6 +60,8 @@ def smoke_config(name: str) -> ArchConfig:
                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
     if cfg.ssm_state:
         over.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8)
+    if cfg.adapter_rank:
+        over["adapter_rank"] = 8
     if cfg.frontend_dim:
         over["frontend_dim"] = 32
     return cfg.scaled(**over)

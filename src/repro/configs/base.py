@@ -16,8 +16,12 @@ Layer kinds:
   mla_moe      — MLA attention + MoE FFN (deepseek-v3)
   mla_dense    — MLA attention + dense MLP (deepseek-v3 first layers)
   mamba        — Mamba-2 SSD block (attention-free)
-  shared_attn  — full attention whose weights are SHARED across occurrences
-                 (zamba2; the paper's "one bitstream, many tiles" reuse case)
+  hybrid<k>    — zamba2's hybrid layer: shared transformer block ``k`` (one
+                 of ``num_mem_blocks``, whose weights every occurrence
+                 reuses — the paper's "one bitstream, many tiles" case) on
+                 ``concat(x, embedding)``, its output through a per-
+                 occurrence linear into the input of the layer's own Mamba-2
+                 block; ``hybrid0``, ``hybrid1``, … name the block
   enc / dec    — encoder (bidirectional) / decoder (causal + cross-attn)
 """
 
@@ -83,10 +87,14 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_conv_width: int = 4
     ssm_chunk: int = 64
+    ssm_groups: int = 1                        # B/C groups; heads split evenly
+    # --- hybrid (zamba2) ---
+    num_mem_blocks: int = 0                    # shared transformer blocks
+    adapter_rank: int = 0                      # per-occurrence MLP adapter
     # --- enc-dec ---
     encoder_blocks: tuple[tuple[tuple[str, ...], int], ...] = ()
     # --- misc ---
-    act: str = "silu"                          # silu | gelu
+    act: str = "silu"                          # silu | gelu (tanh) | gelu_exact (erf)
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     embed_scale: float = 1.0                   # gemma: sqrt(d); minicpm: 12
@@ -125,6 +133,18 @@ class ArchConfig:
         kinds = {k for u, _ in self.blocks for k in u}
         return "mamba" in kinds
 
+    @property
+    def ssm_layers(self) -> bool:
+        """True if any layer carries Mamba-2 state (``mamba``, ``hybrid<k>``)."""
+        return any(k == "mamba" or hybrid_block(k) is not None
+                   for u, _ in self.blocks for k in u)
+
+    @property
+    def hybrid_layers(self) -> bool:
+        """True if any layer runs a shared transformer block (zamba2)."""
+        return any(hybrid_block(k) is not None
+                   for u, _ in self.blocks for k in u)
+
     def param_count(self) -> int:
         """Analytic parameter count (total, incl. all experts)."""
         return _count_params(self)
@@ -138,12 +158,21 @@ class ArchConfig:
         return dataclasses.replace(self, **overrides)
 
 
+def hybrid_block(kind: str) -> int | None:
+    """The shared block a ``hybrid<k>`` layer calls, or None for any other
+    kind."""
+    if kind.startswith("hybrid") and kind[6:].isdigit():
+        return int(kind[6:])
+    return None
+
+
 def _ffn_params(cfg: ArchConfig, d_ff: int) -> int:
     return 3 * cfg.d_model * d_ff  # SwiGLU w1/w3/w2
 
 
-def _attn_params(cfg: ArchConfig) -> int:
+def _attn_params(cfg: ArchConfig, d_in: int | None = None) -> int:
     hd = cfg.resolved_head_dim
+    d_in = d_in or cfg.d_model
     if cfg.kv_lora_rank:  # MLA
         q = cfg.d_model * cfg.q_lora_rank + \
             cfg.q_lora_rank * cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
@@ -151,8 +180,8 @@ def _attn_params(cfg: ArchConfig) -> int:
             cfg.kv_lora_rank * cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
         o = cfg.num_heads * cfg.v_head_dim * cfg.d_model
         return q + kv + o
-    q = cfg.d_model * cfg.num_heads * hd
-    kv = 2 * cfg.d_model * cfg.num_kv_heads * hd
+    q = d_in * cfg.num_heads * hd
+    kv = 2 * d_in * cfg.num_kv_heads * hd
     o = cfg.num_heads * hd * cfg.d_model
     return q + kv + o
 
@@ -160,17 +189,29 @@ def _attn_params(cfg: ArchConfig) -> int:
 def _mamba_params(cfg: ArchConfig) -> int:
     d_inner = cfg.ssm_expand * cfg.d_model
     nheads = d_inner // cfg.ssm_head_dim
-    in_proj = cfg.d_model * (2 * d_inner + 2 * cfg.ssm_state + nheads)
-    conv = cfg.ssm_conv_width * (d_inner + 2 * cfg.ssm_state)
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
+    in_proj = cfg.d_model * (2 * d_inner + bc + nheads)
+    conv = (cfg.ssm_conv_width + 1) * (d_inner + bc)      # weights + bias
     out = d_inner * cfg.d_model
-    return in_proj + conv + out + 2 * nheads  # + A_log, D
+    # + A_log, D, dt_bias and the gated norm's scale
+    return in_proj + conv + out + 3 * nheads + d_inner
+
+
+def shared_block_params(cfg: ArchConfig) -> int:
+    """One shared transformer block of a hybrid model: attention on
+    ``concat(x, embedding)`` (width ``2 d_model``), the MLP, two norms."""
+    d = cfg.d_model
+    return _attn_params(cfg, 2 * d) + _ffn_params(cfg, cfg.d_ff) + 3 * d
 
 
 def _layer_params(cfg: ArchConfig, kind: str) -> int:
     norms = 2 * cfg.d_model
     if kind == "mamba":
         return _mamba_params(cfg) + cfg.d_model
-    if kind in ("dense", "local", "global", "enc", "shared_attn"):
+    if hybrid_block(kind) is not None:   # own mamba + adapter + linear
+        adapter = cfg.adapter_rank * (cfg.d_model + 2 * cfg.d_ff)
+        return _mamba_params(cfg) + cfg.d_model + adapter + cfg.d_model ** 2
+    if kind in ("dense", "local", "global", "enc"):
         return _attn_params(cfg) + _ffn_params(cfg, cfg.d_ff) + norms
     if kind == "dec":
         return 2 * _attn_params(cfg) + _ffn_params(cfg, cfg.d_ff) + 3 * cfg.d_model
@@ -199,8 +240,7 @@ def _count_params(cfg: ArchConfig, active_only: bool = False) -> int:
                 act_e = (cfg.experts_per_token + cfg.num_shared_experts) * \
                     _ffn_params(cfg, cfg.moe_d_ff)
                 n = att + router + act_e + 2 * cfg.d_model
-            if kind == "shared_attn":
-                total += n          # weights shared across all repetitions
-            else:
-                total += n * rep
+            total += n * rep
+    # shared blocks: held once, whichever layers call them
+    total += cfg.num_mem_blocks * shared_block_params(cfg)
     return total

@@ -27,6 +27,10 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import interpret_mode
 
+# the custom call's instruction name in compiled HLO (``ssd_chunk.<n>``),
+# which a device trace finds the kernel by
+KERNEL_NAME = "ssd_chunk"
+
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, acum_ref, *, chunk: int):
     x = x_ref[0, 0].astype(jnp.float32)      # (L, p)
@@ -99,6 +103,7 @@ def ssd_chunk(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array, *,
             jax.ShapeDtypeStruct((bh, nc, 1, L), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(x, a, b, c)
     return y, st, acum
 

@@ -77,6 +77,14 @@ def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
     return terms
 
 
+def _hybrid_args(cfg, gp, x) -> dict:
+    """A hybrid group's extra inputs: its shared blocks and, standing in
+    for the token embedding, an activation of the same shape."""
+    if not cfg.hybrid_layers:
+        return {}
+    return {"emb": x, "shared": gp["shared"]}
+
+
 def _body_costs(cfg, shape: str, mesh, rules) -> dict:
     """Per-trip cost of every scanned layer-group body.
 
@@ -93,7 +101,7 @@ def _body_costs(cfg, shape: str, mesh, rules) -> dict:
     from repro.models import params as pm2
     from repro.models import transformer as tfm
     from repro.models.transformer import cache_spec as cs_full
-    from repro.models.transformer import group_spec
+    from repro.models.transformer import group_spec, model_spec
 
     info = steps_lib.SHAPES[shape]
     kind = info["kind"]
@@ -115,6 +123,8 @@ def _body_costs(cfg, shape: str, mesh, rules) -> dict:
                            "coll": 0.0})
             continue
         gspec = group_spec(cfg, unit, 1)
+        if cfg.hybrid_layers:    # the shared blocks, costed with each group
+            gspec["shared"] = model_spec(cfg)["shared"]
         gp_abs = pm2.abstract(gspec)
         gp_sh = jax.tree.map(
             lambda s: shd.named_sharding(mesh, rules, s.axes, s.shape),
@@ -123,7 +133,8 @@ def _body_costs(cfg, shape: str, mesh, rules) -> dict:
         if kind == "train":
             def body(gp, x, _u=unit):
                 y, _, aux = tfm.group_fwd(gp, x, _u, 1, cfg,
-                                          positions=positions)
+                                          positions=positions,
+                                          **_hybrid_args(cfg, gp, x))
                 return jnp.sum(y.astype(jnp.float32)) + aux
             fn = jax.grad(body, argnums=(0, 1))
             args = (gp_abs, h_sds)
@@ -142,7 +153,8 @@ def _body_costs(cfg, shape: str, mesh, rules) -> dict:
 
             def body(gp, x, c, _u=unit):
                 y, nc, _ = tfm.group_fwd(gp, x, _u, 1, cfg,
-                                         positions=positions, caches=c)
+                                         positions=positions, caches=c,
+                                         **_hybrid_args(cfg, gp, x))
                 return y, nc
             fn = body
             args = (gp_abs, h_sds, c_abs)

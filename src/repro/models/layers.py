@@ -91,12 +91,25 @@ def mlp_spec(cfg: ArchConfig, d_ff: int | None = None) -> dict:
 
 
 def _act(cfg: ArchConfig, x):
+    if cfg.act == "gelu_exact":
+        return jax.nn.gelu(x, approximate=False)
     return jax.nn.gelu(x) if cfg.act == "gelu" else jax.nn.silu(x)
 
 
-def mlp_fwd(p: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
-    h = _act(cfg, x @ p["w_gate"]) * (x @ p["w_up"])
-    return h @ p["w_down"]
+def mlp_fwd(p: dict, x: jax.Array, cfg: ArchConfig,
+            adapter: tuple[jax.Array, jax.Array] | None = None) -> jax.Array:
+    """Gated MLP.  ``adapter``: a low-rank ``(down, up)`` pair whose product
+    is added to the gate and up projections (``up`` holds the gate's
+    columns, then the up projection's), as zamba2's per-occurrence
+    adapter on a shared block."""
+    if adapter is None:
+        h = _act(cfg, x @ p["w_gate"]) * (x @ p["w_up"])
+        return h @ p["w_down"]
+    lo = (x @ adapter[0]) @ adapter[1]
+    f = p["w_gate"].shape[1]
+    gate = x @ p["w_gate"] + lo[..., :f]
+    up = x @ p["w_up"] + lo[..., f:]
+    return (_act(cfg, gate) * up) @ p["w_down"]
 
 
 def cache_update(cache: jax.Array, new: jax.Array, idx, *, axis: int):
@@ -148,12 +161,15 @@ def write_rows(cache: jax.Array, layer, new: jax.Array,
 # ---------------------------------------------------------------------------
 # Attention (GQA family)
 # ---------------------------------------------------------------------------
-def attn_spec(cfg: ArchConfig) -> dict:
+def attn_spec(cfg: ArchConfig, d_in: int | None = None) -> dict:
+    """Q/K/V read ``d_in`` features (``d_model`` where None); the output
+    projection writes ``d_model``."""
     hd = cfg.resolved_head_dim
+    d_in = d_in or cfg.d_model
     return {
-        "wq": dense(cfg.d_model, cfg.num_heads * hd, "embed", "heads"),
-        "wk": dense(cfg.d_model, cfg.num_kv_heads * hd, "embed", "kv_heads"),
-        "wv": dense(cfg.d_model, cfg.num_kv_heads * hd, "embed", "kv_heads"),
+        "wq": dense(d_in, cfg.num_heads * hd, "embed", "heads"),
+        "wk": dense(d_in, cfg.num_kv_heads * hd, "embed", "kv_heads"),
+        "wv": dense(d_in, cfg.num_kv_heads * hd, "embed", "kv_heads"),
         "wo": dense(cfg.num_heads * hd, cfg.d_model, "heads", "embed"),
     }
 
@@ -294,7 +310,7 @@ def attn_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *, kind: str,
              ) -> tuple[jax.Array, dict | None]:
     """Unified attention forward.
 
-    x: (B, S, D). kind: dense|local|global|shared_attn|enc|cross.
+    x: (B, S, D). kind: dense|local|global|hybrid<k>|enc|cross.
     cache: None (train/prefill without cache) or
       {"k": (B, Hkv, Smax, hd), "v": ..., "index": scalar} for decode.
     x_kv: encoder output for cross attention.

@@ -173,10 +173,14 @@ def prefill_chunk(params: dict, cfg: ArchConfig, tokens: jax.Array,
     is a *traced* int32 scalar selecting the in-chunk position whose
     logits are returned — the chunk length C is the only static shape, so
     one compiled signature serves every prompt sharing a bucket size.
-    Returns (logits (B, V), caches).
+    SSM layers cannot have padding overwritten later, so they are told the
+    real length ``last_index + 1`` and hold their conv window and SSD state
+    to it.  Returns (logits (B, V), caches).
     """
     pos0 = _current_index(cfg, caches)
-    h, caches, _ = tfm.forward(params, cfg, tokens, pos0=pos0, caches=caches)
+    length = last_index + 1 if cfg.ssm_layers else None
+    h, caches, _ = tfm.forward(params, cfg, tokens, pos0=pos0, caches=caches,
+                               length=length)
     logits = tfm.unembed(params, h, cfg)
     sel = jax.lax.dynamic_slice_in_dim(logits, last_index, 1, axis=1)
     return sel[:, 0], caches
@@ -224,7 +228,18 @@ def build_step_graph(cfg: ArchConfig, batch_shape: tuple[int, int]):
     h = g.apply(embed_op, p_in, tok)
 
     positions = jnp.arange(s)
+    emb = h
     for gi, (unit, rep) in enumerate(cfg.blocks):
+        if cfg.hybrid_layers:   # the shared blocks also read the embedding
+            def stage_fn(p, x, e, _gi=gi, _unit=unit, _rep=rep):
+                y, _, _ = tfm.group_fwd(p[f"g{_gi}"], x, _unit, _rep, cfg,
+                                        positions=positions, emb=e,
+                                        shared=p["shared"])
+                return y
+            op = Operator(f"{cfg.name}/g{gi}", 3, stage_fn, TileClass.LARGE)
+            h = g.apply(op, p_in, h, emb)
+            continue
+
         def stage_fn(p, x, _gi=gi, _unit=unit, _rep=rep):
             y, _, _ = tfm.group_fwd(p[f"g{_gi}"], x, _unit, _rep, cfg,
                                     positions=positions)

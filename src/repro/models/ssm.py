@@ -9,8 +9,16 @@ the five matmuls back together on the MXU).
 
 Pipeline: projections -> causal depthwise conv on [x|B|C] -> softplus dt ->
 SSD scan (Pallas chunk kernel) -> D-skip -> gated RMSNorm -> out projection.
+B and C come in ``ssm_groups`` groups of ``ssm_state``: heads are split
+evenly among the groups in order (zamba2: heads 0-55 read group 0, 56-111
+group 1), and the gated RMSNorm normalizes each group's share of the
+``d_inner`` channels on its own.
 Decode keeps O(1) state: rolling conv window + (h, n, p) SSD state — this is
 why mamba2/zamba2 are the archs that run ``long_500k``.
+
+A chunk right-padded past its ``length`` real tokens leaves both states
+where the last real token left them: dt is 0 at a padded position (decay
+e^0 = 1, input dt·x = 0), and the conv window is taken at the real length.
 """
 
 from __future__ import annotations
@@ -32,7 +40,7 @@ def _dims(cfg: ArchConfig):
 
 def ssm_spec(cfg: ArchConfig) -> dict:
     d_inner, nheads = _dims(cfg)
-    n, w = cfg.ssm_state, cfg.ssm_conv_width
+    n, w = cfg.ssm_groups * cfg.ssm_state, cfg.ssm_conv_width
     return {
         "w_z": dense(cfg.d_model, d_inner, "embed", "ssm_in"),
         "w_x": dense(cfg.d_model, d_inner, "embed", "ssm_in"),
@@ -54,15 +62,23 @@ def ssm_spec(cfg: ArchConfig) -> dict:
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
-                 state: jax.Array | None = None):
-    """Depthwise causal conv1d. x: (B, S, C), w: (W, C), state: (B, W-1, C)."""
+                 state: jax.Array | None = None, length=None):
+    """Depthwise causal conv1d. x: (B, S, C), w: (W, C), state: (B, W-1, C).
+    The new state is the window that ends at the ``length``-th input (all
+    ``S`` where None)."""
     width = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], width - 1, x.shape[2]), x.dtype)
         full = jnp.concatenate([pad, x], axis=1)
     else:
         full = jnp.concatenate([state.astype(x.dtype), x], axis=1)
-    new_state = full[:, -(width - 1):] if width > 1 else None
+    if width <= 1:
+        new_state = None
+    elif length is None:
+        new_state = full[:, -(width - 1):]
+    else:
+        new_state = jax.lax.dynamic_slice_in_dim(full, length, width - 1,
+                                                 axis=1)
     out = sum(w[i].astype(jnp.float32) *
               jax.lax.slice_in_dim(full.astype(jnp.float32), i,
                                    i + x.shape[1], axis=1)
@@ -70,9 +86,34 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return (out + b.astype(jnp.float32)).astype(x.dtype), new_state
 
 
+def heads_of_groups(t: jax.Array, nheads: int, groups: int) -> jax.Array:
+    """(B, S, groups * n) -> (B, S, nheads, n): each head reads its group's
+    n channels; heads are split evenly among the groups in order."""
+    bsz, s, gn = t.shape
+    n = gn // groups
+    t = t.reshape(bsz, s, groups, 1, n)
+    t = jnp.broadcast_to(t, (bsz, s, groups, nheads // groups, n))
+    return t.reshape(bsz, s, nheads, n)
+
+
+def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, groups: int,
+               eps: float) -> jax.Array:
+    """Mamba-2's gated RMSNorm, ``norm(y * silu(z))``, over each group's
+    share of the channels."""
+    g = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    gf = g.astype(jnp.float32)
+    gg = gf.reshape(*gf.shape[:-1], groups, gf.shape[-1] // groups)
+    ms = jnp.mean(gg * gg, axis=-1, keepdims=True)
+    gf = (gg * jax.lax.rsqrt(ms + eps)).reshape(gf.shape)
+    return (gf * scale.astype(jnp.float32)).astype(y.dtype)
+
+
 def ssm_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *,
-            cache: dict | None = None) -> tuple[jax.Array, dict | None]:
-    """x: (B, S, d_model) -> (same, updated cache)."""
+            cache: dict | None = None,
+            length=None) -> tuple[jax.Array, dict | None]:
+    """x: (B, S, d_model) -> (same, updated cache).  ``length``: the number
+    of real tokens of a right-padded chunk (a traced scalar); positions at
+    or past it leave the conv and SSD state untouched."""
     bsz, s, _ = x.shape
     d_inner, nheads = _dims(cfg)
     n, pdim = cfg.ssm_state, cfg.ssm_head_dim
@@ -84,22 +125,26 @@ def ssm_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *,
     dt_raw = x @ p["w_dt"]
 
     cs = cache["conv"] if cache is not None else {"x": None, "b": None, "c": None}
-    xs, ncx = _causal_conv(xs, p["conv_x"], p["conv_bias_x"], cs["x"])
-    bmat, ncb = _causal_conv(bmat, p["conv_b"], p["conv_bias_b"], cs["b"])
-    cmat, ncc = _causal_conv(cmat, p["conv_c"], p["conv_bias_c"], cs["c"])
+    xs, ncx = _causal_conv(xs, p["conv_x"], p["conv_bias_x"], cs["x"], length)
+    bmat, ncb = _causal_conv(bmat, p["conv_b"], p["conv_bias_b"], cs["b"],
+                             length)
+    cmat, ncc = _causal_conv(cmat, p["conv_c"], p["conv_bias_c"], cs["c"],
+                             length)
     xs, bmat, cmat = (jax.nn.silu(t) for t in (xs, bmat, cmat))
     new_conv = {"x": ncx, "b": ncb, "c": ncc}
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) +
                          p["dt_bias"].astype(jnp.float32))     # (B,S,h)
+    if length is not None:
+        dt = jnp.where((jnp.arange(s) < length)[None, :, None], dt, 0.0)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))               # (h,)
     a_full = a[None, None] * dt                                # (B,S,h) <= 0
 
     xh = xs.reshape(bsz, s, nheads, pdim)
     xh = shd.constrain_logical(xh, ("batch", None, "heads", None))
     x_in = (xh.astype(jnp.float32) * dt[..., None]).astype(x.dtype)
-    b_full = jnp.broadcast_to(bmat[:, :, None, :], (bsz, s, nheads, n))
-    c_full = jnp.broadcast_to(cmat[:, :, None, :], (bsz, s, nheads, n))
+    b_full = heads_of_groups(bmat, nheads, cfg.ssm_groups)
+    c_full = heads_of_groups(cmat, nheads, cfg.ssm_groups)
 
     # pad the sequence up to a chunk multiple (padding has a=0, x=0: decay
     # e^0 = 1 passes state through, zero input adds nothing — the final
@@ -133,13 +178,7 @@ def ssm_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *,
         xh.astype(jnp.float32)
     y = y.reshape(bsz, s, d_inner).astype(x.dtype)
 
-    # gated RMSNorm (mamba2): norm(y * silu(z))
-    g = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    gf = g.astype(jnp.float32)
-    ms = jnp.mean(gf * gf, axis=-1, keepdims=True)
-    g = (gf * jax.lax.rsqrt(ms + cfg.norm_eps) *
-         p["gate_norm"].astype(jnp.float32)).astype(x.dtype)
-
+    g = gated_norm(y, z, p["gate_norm"], cfg.ssm_groups, cfg.norm_eps)
     out = g @ p["out_proj"]
     new_cache = None
     if cache is not None:
@@ -149,14 +188,14 @@ def ssm_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *,
 
 def ssm_cache_spec(cfg: ArchConfig, batch: int, dtype=jnp.bfloat16) -> dict:
     d_inner, nheads = _dims(cfg)
-    w = cfg.ssm_conv_width
+    w, gn = cfg.ssm_conv_width, cfg.ssm_groups * cfg.ssm_state
     return {
         "conv": {
             "x": ParamSpec((batch, w - 1, d_inner), ("batch", None, "ssm_in"),
                            "zeros", dtype=dtype),
-            "b": ParamSpec((batch, w - 1, cfg.ssm_state),
+            "b": ParamSpec((batch, w - 1, gn),
                            ("batch", None, None), "zeros", dtype=dtype),
-            "c": ParamSpec((batch, w - 1, cfg.ssm_state),
+            "c": ParamSpec((batch, w - 1, gn),
                            ("batch", None, None), "zeros", dtype=dtype),
         },
         "ssm": ParamSpec((batch, nheads, cfg.ssm_state, cfg.ssm_head_dim),

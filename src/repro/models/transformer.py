@@ -4,9 +4,12 @@ A model is a sequence of *groups*; each group is ``(unit, repeat)`` from
 ``ArchConfig.blocks``.  The unit (a tuple of layer kinds) becomes the body of
 one ``lax.scan`` over ``repeat`` — so an 88-layer dense model compiles ONE
 layer body, and gemma-2's (local, global) alternation compiles exactly two.
-``shared_attn`` layers (zamba2) hold their parameters OUTSIDE the scanned
-stack — one "bitstream", referenced by all repetitions (paper's operator
-reuse).
+The shared transformer blocks of ``hybrid<k>`` layers (zamba2) hold their
+parameters OUTSIDE every scanned stack, at the model's top level — one
+"bitstream" per block, referenced by each occurrence (paper's operator
+reuse); each occurrence's own Mamba-2 block, adapter and linear are stacked
+with the other layers.  A hybrid model also hands every layer the token
+embedding, which the shared blocks read beside the residual stream.
 
 Remat is applied to the scan body (``cfg.remat``: full | dots | none) — the
 main activation-memory knob for the 4k-train shapes.
@@ -20,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import sharding as shd
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, hybrid_block
 from repro.models import params as pm
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
@@ -29,8 +32,8 @@ from repro.models.layers import (attn_cache_spec, attn_fwd, attn_spec,
                                  mlp_spec, rmsnorm_fwd)
 from repro.models.params import ParamSpec, dense, embedding, norm_scale
 
-ATTN_KINDS = ("dense", "local", "global", "shared_attn", "enc", "dec",
-              "mla_dense", "moe", "mla_moe")
+# the leaves of a self-attention cache that the ragged decode carries
+KV_LEAVES = ("k", "v", "index")
 
 
 # ---------------------------------------------------------------------------
@@ -40,6 +43,13 @@ def layer_spec(cfg: ArchConfig, kind: str) -> dict:
     d = cfg.d_model
     if kind == "mamba":
         return {"ln1": norm_scale(d), "mixer": ssm_lib.ssm_spec(cfg)}
+    if hybrid_block(kind) is not None:
+        # the occurrence's own leaves; the shared block is shared_block_spec
+        return {"ln1": norm_scale(d), "mixer": ssm_lib.ssm_spec(cfg),
+                "adapter_down": dense(d, cfg.adapter_rank, "embed", None),
+                "adapter_up": dense(cfg.adapter_rank, 2 * cfg.d_ff,
+                                    None, "ffn"),
+                "linear": dense(d, d, "embed", None)}
     s: dict[str, Any] = {"ln1": norm_scale(d)}
     s["attn"] = mla_spec(cfg) if kind.startswith("mla") else attn_spec(cfg)
     if kind == "dec":
@@ -54,19 +64,19 @@ def layer_spec(cfg: ArchConfig, kind: str) -> dict:
     return s
 
 
+def shared_block_spec(cfg: ArchConfig) -> dict:
+    """One shared transformer block of a hybrid model: attention reads
+    ``concat(x, embedding)`` (``2 d_model``) and writes ``d_model``; no
+    residual of its own."""
+    d = cfg.d_model
+    return {"ln_in": norm_scale(2 * d), "attn": attn_spec(cfg, 2 * d),
+            "ln_ff": norm_scale(d), "ffn": mlp_spec(cfg)}
+
+
 def group_spec(cfg: ArchConfig, unit: tuple[str, ...], rep: int) -> dict:
-    stacked = {}
-    shared = {}
-    for i, kind in enumerate(unit):
-        if kind == "shared_attn":
-            if "shared_attn" not in shared:      # one bitstream for the group
-                shared["shared_attn"] = layer_spec(cfg, kind)
-        else:
-            stacked[f"{i}:{kind}"] = layer_spec(cfg, kind)
-    out = {"layers": pm.stack_tree(stacked, rep)}
-    if shared:
-        out["shared"] = shared
-    return out
+    stacked = {f"{i}:{kind}": layer_spec(cfg, kind)
+               for i, kind in enumerate(unit)}
+    return {"layers": pm.stack_tree(stacked, rep)}
 
 
 def model_spec(cfg: ArchConfig) -> dict:
@@ -80,6 +90,9 @@ def model_spec(cfg: ArchConfig) -> dict:
         spec["enc_norm"] = norm_scale(cfg.d_model)
     for gi, (unit, rep) in enumerate(cfg.blocks):
         spec[f"g{gi}"] = group_spec(cfg, unit, rep)
+    if cfg.hybrid_layers:
+        spec["shared"] = {f"block{k}": shared_block_spec(cfg)
+                          for k in range(cfg.num_mem_blocks)}
     spec["final_norm"] = norm_scale(cfg.d_model)
     if not cfg.tie_embeddings:
         spec["lm_head"] = dense(cfg.d_model, cfg.vocab_size, "embed", "vocab")
@@ -97,6 +110,9 @@ def model_spec(cfg: ArchConfig) -> dict:
 def layer_cache_spec(cfg: ArchConfig, kind: str, batch: int, max_len: int):
     if kind == "mamba":
         return ssm_lib.ssm_cache_spec(cfg, batch)
+    if hybrid_block(kind) is not None:   # its attention's K/V, its SSM state
+        return {**attn_cache_spec(cfg, batch, max_len),
+                **ssm_lib.ssm_cache_spec(cfg, batch)}
     if kind.startswith("mla"):
         return mla_cache_spec(cfg, batch, max_len)
     if kind == "dec":
@@ -115,10 +131,8 @@ def layer_cache_spec(cfg: ArchConfig, kind: str, batch: int, max_len: int):
 def cache_spec(cfg: ArchConfig, batch: int, max_len: int) -> dict:
     spec = {}
     for gi, (unit, rep) in enumerate(cfg.blocks):
-        g = {}
-        for i, kind in enumerate(unit):
-            key = f"{i}:{kind}" if kind != "shared_attn" else f"{i}:shared_attn"
-            g[key] = layer_cache_spec(cfg, kind, batch, max_len)
+        g = {f"{i}:{kind}": layer_cache_spec(cfg, kind, batch, max_len)
+             for i, kind in enumerate(unit)}
         spec[f"g{gi}"] = pm.stack_tree(g, rep)
     return spec
 
@@ -131,15 +145,25 @@ def _maybe_post(cfg, p, key, x):
 
 
 def layer_fwd(p: dict, x: jax.Array, kind: str, cfg: ArchConfig, *,
-              positions: jax.Array, cache=None, enc_out=None, layer=None):
+              positions: jax.Array, cache=None, enc_out=None, layer=None,
+              emb=None, shared=None, length=None):
     """One layer. Returns (x, new_cache, aux_loss).  ``layer`` marks a
-    layer-stacked self-attention cache (see :func:`attn_fwd`)."""
+    layer-stacked self-attention cache (see :func:`attn_fwd`); ``emb`` and
+    ``shared`` are a hybrid model's token embedding and shared blocks;
+    ``length`` is the real length of a right-padded prefill chunk, which
+    the SSM layers hold their state to."""
     aux = jnp.zeros((), jnp.float32)
     rs = cfg.residual_scale
     if kind == "mamba":
         h = rmsnorm_fwd(p["ln1"], x, cfg.norm_eps)
-        h, new_cache = ssm_lib.ssm_fwd(p["mixer"], h, cfg, cache=cache)
+        h, new_cache = ssm_lib.ssm_fwd(p["mixer"], h, cfg, cache=cache,
+                                       length=length)
         return x + rs * h, new_cache, aux
+    if hybrid_block(kind) is not None:
+        blk = shared[f"block{hybrid_block(kind)}"]
+        x, new_cache = hybrid_fwd(p, blk, x, emb, cfg, positions=positions,
+                                  cache=cache, layer=layer, length=length)
+        return x, new_cache, aux
 
     h = rmsnorm_fwd(p["ln1"], x, cfg.norm_eps)
     if kind.startswith("mla"):
@@ -178,20 +202,61 @@ def layer_fwd(p: dict, x: jax.Array, kind: str, cfg: ArchConfig, *,
     return x + rs * h, new_cache, aux
 
 
+def hybrid_fwd(p: dict, blk: dict, x: jax.Array, emb: jax.Array,
+               cfg: ArchConfig, *, positions, cache=None, layer=None,
+               length=None):
+    """zamba2's hybrid layer (``Zamba2HybridLayer``): the shared block
+    ``blk`` on ``concat(x, emb)``, then this occurrence's linear ``p``
+    adds its output to the input of this layer's own Mamba-2 block; the
+    residual is ``x`` alone:
+
+        t = ln_in(concat(x, emb));  a = ln_ff(attn(t))
+        f = Wdown . act(a Wgate + a Adn Aup_g) * (a Wup + a Adn Aup_u)
+        x = x + mamba(ln1(x + f L))
+
+    ``cache`` holds the attention's K/V leaves (``KV_LEAVES``) and the
+    Mamba-2 block's ``conv``/``ssm`` state.  Returns (x, new_cache)."""
+    t = jnp.concatenate([x, emb.astype(x.dtype)], axis=-1)
+    t = rmsnorm_fwd(blk["ln_in"], t, cfg.norm_eps)
+    kv_c = None if cache is None else {n: cache[n] for n in KV_LEAVES}
+    a, kv_c = attn_fwd(blk["attn"], t, cfg, kind="hybrid",
+                       positions=positions, cache=kv_c, layer=layer)
+    a = rmsnorm_fwd(blk["ln_ff"], a, cfg.norm_eps)
+    f = mlp_fwd(blk["ffn"], a, cfg,
+                adapter=(p["adapter_down"], p["adapter_up"]))
+    h = rmsnorm_fwd(p["ln1"], x + f @ p["linear"], cfg.norm_eps)
+    ssm_c = None if cache is None else {"conv": cache["conv"],
+                                        "ssm": cache["ssm"]}
+    h, ssm_c = ssm_lib.ssm_fwd(p["mixer"], h, cfg, cache=ssm_c,
+                               length=length)
+    new_cache = None if cache is None else {**kv_c, **ssm_c}
+    return x + cfg.residual_scale * h, new_cache
+
+
 def group_fwd(gp: dict, x: jax.Array, unit: tuple[str, ...], rep: int,
-              cfg: ArchConfig, *, positions, caches=None, enc_out=None):
+              cfg: ArchConfig, *, positions, caches=None, enc_out=None,
+              emb=None, shared=None, length=None):
     """Scan ``rep`` repetitions of ``unit``. Returns (x, new_caches, aux).
 
     In the ragged decode (2-D ``positions``) every self-attention K/V cache
-    rides in the scan carry as its layer-stacked arrays, and each layer
-    writes its rows into it in place; every other cache is scanned as
-    ``xs`` and comes back as ``ys``.
+    (the ``KV_LEAVES`` of a layer's cache) rides in the scan carry as its
+    layer-stacked arrays, and each layer writes its rows into it in place;
+    every other cache leaf (a hybrid layer's SSM state too) is scanned as
+    ``xs`` and comes back as ``ys``.  ``emb``, ``shared`` and ``length``
+    reach every layer (:func:`layer_fwd`).
     """
-    shared = gp.get("shared", {})
     ragged = caches is not None and getattr(positions, "ndim", 0) >= 2
-    kv = {k: c for k, c in (caches or {}).items() if ragged and "k" in c}
-    scanned = (None if caches is None else
-               {k: c for k, c in caches.items() if k not in kv})
+    kv = {k: {n: c[n] for n in KV_LEAVES}
+          for k, c in (caches or {}).items() if ragged and "k" in c}
+    scanned = None
+    if caches is not None:
+        scanned = {}
+        for k, c in caches.items():
+            rest = {n: v for n, v in c.items()
+                    if k not in kv or n not in KV_LEAVES}
+            if rest:
+                scanned[k] = rest
+    extra = dict(emb=emb, shared=shared, length=length)
 
     def body(carry, xs):
         x, kv = carry
@@ -201,15 +266,19 @@ def group_fwd(gp: dict, x: jax.Array, unit: tuple[str, ...], rep: int,
         new_cache_sl = {} if cache_sl is not None else None
         for i, kind in enumerate(unit):
             key = f"{i}:{kind}"
-            p = shared["shared_attn"] if kind == "shared_attn" else layer_p[key]
+            p = layer_p[key]
             if key in kv:
-                x, kv[key], aux = layer_fwd(p, x, kind, cfg,
-                                            positions=positions,
-                                            cache=kv[key], layer=layer)
+                c = {**kv[key], **cache_sl.get(key, {})}
+                x, nc, aux = layer_fwd(p, x, kind, cfg, positions=positions,
+                                       cache=c, layer=layer, **extra)
+                kv[key] = {n: nc[n] for n in KV_LEAVES}
+                rest = {n: v for n, v in nc.items() if n not in KV_LEAVES}
+                if rest:
+                    new_cache_sl[key] = rest
             else:
                 c = cache_sl[key] if cache_sl is not None else None
                 x, nc, aux = layer_fwd(p, x, kind, cfg, positions=positions,
-                                       cache=c, enc_out=enc_out)
+                                       cache=c, enc_out=enc_out, **extra)
                 if new_cache_sl is not None:
                     new_cache_sl[key] = nc
             aux_total += aux
@@ -239,7 +308,8 @@ def group_fwd(gp: dict, x: jax.Array, unit: tuple[str, ...], rep: int,
         (x, kv), (new_caches, auxs) = jax.lax.scan(
             body, (x, kv), (gp["layers"], scanned, layers))
     if caches is not None:
-        new_caches = {**new_caches, **kv}
+        new_caches = {k: {**new_caches.get(k, {}), **kv.get(k, {})}
+                      for k in caches}
     return x, new_caches, jnp.sum(auxs)
 
 
@@ -276,8 +346,9 @@ def encode(params: dict, cfg: ArchConfig, enc_in: jax.Array) -> jax.Array:
 def forward(params: dict, cfg: ArchConfig, tokens: jax.Array, *,
             pos0: jax.Array | int = 0, caches: dict | None = None,
             enc_out: jax.Array | None = None,
-            patch_embeds: jax.Array | None = None):
-    """Decoder stack. Returns (hidden, new_caches, aux_loss)."""
+            patch_embeds: jax.Array | None = None, length=None):
+    """Decoder stack. Returns (hidden, new_caches, aux_loss).  ``length``:
+    the real length of a right-padded chunk, for the SSM layers."""
     h = embed_tokens(params, tokens, cfg)
     if patch_embeds is not None:     # vlm stub: patches replace leading slots
         pe = (patch_embeds.astype(h.dtype) @ params["frontend_proj"])
@@ -292,10 +363,14 @@ def forward(params: dict, cfg: ArchConfig, tokens: jax.Array, *,
 
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: dict | None = {} if caches is not None else None
+    extra = {"length": length}
+    if cfg.hybrid_layers:
+        extra.update(emb=h, shared=params["shared"])
     for gi, (unit, rep) in enumerate(cfg.blocks):
         c = caches[f"g{gi}"] if caches is not None else None
         h, nc, aux = group_fwd(params[f"g{gi}"], h, unit, rep, cfg,
-                               positions=positions, caches=c, enc_out=enc_out)
+                               positions=positions, caches=c, enc_out=enc_out,
+                               **extra)
         h = shd.constrain_logical(h, ("batch", None, None))
         if new_caches is not None:
             new_caches[f"g{gi}"] = nc

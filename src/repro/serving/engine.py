@@ -82,6 +82,15 @@ class Request:
     shed_reason: str | None = None
 
 
+def _first_leaf(tree, name: str):
+    """The first leaf of ``tree`` under a dict key ``name`` (a layer's K
+    cache ``"k"``, its SSD state ``"ssm"``), or None."""
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        if any(getattr(k, "key", None) == name for k in path):
+            return leaf
+    return None
+
+
 @jax.jit
 def _fused_tick_update(logits, cur_tokens, slot_pos, live):
     """One on-device update for a decode tick: greedy-sample every live
@@ -133,9 +142,10 @@ class ServeEngine:
         self.cur_tokens = jnp.zeros((batch, 1), jnp.int32)
         self._live_mask = jnp.zeros((batch,), jnp.int32)
         self._decode_prefetched = False
-        # decode ticks whose input cache the step consumed (donated): every
-        # tick once the decode executable is downloaded
+        # decode ticks whose input K/V and SSM state the step consumed
+        # (donated): every tick once the decode executable is downloaded
         self.kv_donated_ticks = 0
+        self.ssm_donated_ticks = 0
 
     # -- fabric management (relocatable bitstreams, DESIGN.md §6) ------------
     def compact(self) -> int:
@@ -296,11 +306,15 @@ class ServeEngine:
         """Batched ragged decode over ``live`` slots with ONE host transfer:
         sample/advance happens fused on device and the host reads a single
         packed (token, position) array per tick."""
-        probe = jax.tree.leaves(self.caches)[0]
+        kv, ssm = _first_leaf(self.caches, "k"), _first_leaf(self.caches,
+                                                             "ssm")
         with _span("engine.decode"):
             logits, self.caches = self._decode(
                 self.params, self.cur_tokens, self.caches, self.slot_pos)
-        self.kv_donated_ticks += probe.is_deleted()
+        if kv is not None:
+            self.kv_donated_ticks += kv.is_deleted()
+        if ssm is not None:
+            self.ssm_donated_ticks += ssm.is_deleted()
         with _span("engine.sample"):
             self.cur_tokens, self.slot_pos, packed = _fused_tick_update(
                 logits, self.cur_tokens, self.slot_pos, self._live_mask)

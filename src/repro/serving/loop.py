@@ -34,7 +34,10 @@ Token streams for admitted requests are bit-identical to the synchronous
 engine's: chunking changes only *when* KV entries are written, the ragged
 decode path reads every slot at its own position either way, and padded
 chunk positions are causally masked then overwritten by decode before any
-query can attend to them.
+query can attend to them.  SSM layers (mamba, zamba2's hybrids) instead
+carry their state from chunk to chunk, told each chunk's real length so
+that padding never enters it; their numbers agree with the whole-prompt
+prefill's to rounding, since the SSD scan is split at other points.
 """
 
 from __future__ import annotations
@@ -81,6 +84,9 @@ class EventLoopEngine(ServeEngine):
         self.queue_delay_hist = Histogram()  # submit -> admission, us
         self.ticks = 0                       # step() calls
         self.prefill_ticks = 0               # of those, ticks that ran a chunk
+        # padded prefill positions that the SSM layers held out of their
+        # state (``prefill_chunk``'s real length)
+        self.ssm_pad_tokens = 0
         pc = lambda p, toks, c, li: mdl.prefill_chunk(p, cfg, toks, c, li)
         if overlay is not None:
             self._prefill_chunk = overlay.jit(
@@ -201,6 +207,8 @@ class EventLoopEngine(ServeEngine):
             size = self._chunk_size(n - off)
             toks = req.prompt[off:off + size]
             last = len(toks) - 1          # last REAL token within this chunk
+            if self.cfg.ssm_layers:
+                self.ssm_pad_tokens += size - len(toks)
             toks = toks + [0] * (size - len(toks))
             logits, st["c1"] = self._prefill_chunk(
                 self.params, jnp.asarray(toks, jnp.int32)[None], st["c1"],
@@ -247,5 +255,7 @@ class EventLoopEngine(ServeEngine):
             "ticks": self.ticks,
             "prefill_ticks": self.prefill_ticks,
             "kv_donated_ticks": self.kv_donated_ticks,
+            "ssm_donated_ticks": self.ssm_donated_ticks,
+            "ssm_pad_tokens": self.ssm_pad_tokens,
             "failures": self.overlay_failures(),
         }

@@ -61,7 +61,7 @@ def test_full_config_layer_count(arch):
     ("deepseek-v3-671b", 671e9), ("mistral-large-123b", 123e9),
     ("gemma2-27b", 27e9), ("phi3-mini-3.8b", 3.8e9),
     ("pixtral-12b", 12e9), ("minicpm-2b", 2.7e9),
-    ("mamba2-130m", 130e6)])
+    ("mamba2-130m", 130e6), ("zamba2-7b", 7.35e9)])
 def test_full_config_param_count_near_nameplate(arch, target_b):
     n = get_config(arch).param_count()
     assert 0.75 * target_b < n < 1.35 * target_b, f"{arch}: {n/1e9:.2f}B"
@@ -137,14 +137,30 @@ def test_encdec_prefill_and_decode_run():
 
 
 def test_zamba2_shared_attention_is_actually_shared():
-    """zamba2's shared_attn params appear once per group, not per repetition
-    (the paper's bitstream-reuse case)."""
+    """zamba2's two shared transformer blocks are held once, at the model's
+    top level, not per layer or per repetition (the paper's bitstream-reuse
+    case), and the 13 hybrid layers call them A, B, A, B, ... by
+    occurrence; each hybrid keeps its own Mamba2 block, adapter and linear,
+    so the model runs 81 Mamba2 blocks."""
     cfg = smoke_config("zamba2-7b")
     spec = model_spec(cfg)
-    g1 = spec["g1"]
-    assert "shared" in g1 and "shared_attn" in g1["shared"]
-    wq = g1["shared"]["shared_attn"]["attn"]["wq"]
-    assert len(wq.shape) == 2            # NOT stacked with a layer dim
+    assert set(spec["shared"]) == {"block0", "block1"}
+    for blk in spec["shared"].values():
+        wq = blk["attn"]["wq"]
+        assert len(wq.shape) == 2        # NOT stacked with a layer dim
+        assert wq.shape[0] == 2 * cfg.d_model   # reads concat(x, embedding)
+    full = get_config("zamba2-7b")
+    kinds = [k for u, r in full.blocks for _ in range(r) for k in u]
+    hybrids = [k for k in kinds if k.startswith("hybrid")]
+    assert len(kinds) == 81 and len(hybrids) == 13
+    assert hybrids == ["hybrid0", "hybrid1"] * 6 + ["hybrid0"]
+    assert [i for i, k in enumerate(kinds) if k in hybrids] == \
+        [6, 11, 17, 23, 29, 35, 41, 47, 53, 59, 65, 71, 77]
+    for gi, (unit, _) in enumerate(cfg.blocks):
+        for i, kind in enumerate(unit):
+            layer = spec[f"g{gi}"]["layers"][f"{i}:{kind}"]
+            assert "mixer" in layer
+            assert ("linear" in layer) == kind.startswith("hybrid")
 
 
 def test_gemma2_local_global_alternation_compiles_two_bodies():

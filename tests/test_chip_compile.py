@@ -79,6 +79,11 @@ def _kernel_case(name: str, sds):
                                                 interpret=False),
                 (sds((1, 512, 24, 64), bf16), sds((1, 512, 24), f32),
                  sds((1, 512, 24, 128), bf16), sds((1, 512, 24, 128), bf16)))
+    if name == "ssd_scan_zamba2":              # 112 heads, p 64, n 64
+        return (lambda x, a, b, c: ssd_scan.ssd(x, a, b, c, chunk=128,
+                                                interpret=False),
+                (sds((1, 128, 112, 64), bf16), sds((1, 128, 112), f32),
+                 sds((1, 128, 112, 64), bf16), sds((1, 128, 112, 64), bf16)))
     if name == "vmul_reduce":                  # 16 M elements per vector
         v = sds((16 * 1024 * 1024,), f32)
         return (lambda a, b: vr.vmul_reduce(a, b, interpret=False), (v, v))
@@ -87,17 +92,29 @@ def _kernel_case(name: str, sds):
 
 @pytest.mark.parametrize("name", ["rmsnorm", "flash_attention_phi3",
                                   "flash_attention_gqa", "ssd_scan",
-                                  "vmul_reduce"])
+                                  "ssd_scan_zamba2", "vmul_reduce"])
 def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
     fn, args = _kernel_case(name, sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if name.startswith("ssd_scan"):
+        # the instruction name a device trace finds the kernel by
+        assert re.search(rf"%{ssd_scan.KERNEL_NAME}(\.\d+)? = .* custom-call",
+                         compiled.as_text())
 
 
-# the served cells' widths at two layers: (arch, batch); max_len 1024
-DECODE_CASES = [("phi3-mini-3.8b", 4), ("minicpm-2b", 6)]
+# the served cells' widths at two layers (zamba2: two repetitions of a
+# mamba layer and a hybrid one): (arch, batch); max_len 1024
+DECODE_CASES = [("phi3-mini-3.8b", 4), ("minicpm-2b", 6), ("zamba2-7b", 16)]
+
+
+def _two_layers(arch: str, layers: int):
+    cfg = get_config(arch)
+    if cfg.hybrid_layers:
+        return cfg.scaled(blocks=((("mamba", "hybrid0"), layers),))
+    return cfg.scaled(blocks=((("dense",), layers),))
 
 
 def _copied_shapes(hlo: str) -> set[tuple[int, ...]]:
@@ -115,7 +132,7 @@ def _copied_shapes(hlo: str) -> set[tuple[int, ...]]:
 def test_decode_updates_cache_in_place_for_v5e(arch, batch, one_chip,
                                                no_compile_cache):
     layers, max_len = 2, 1024
-    cfg = get_config(arch).scaled(blocks=((("dense",), layers),))
+    cfg = _two_layers(arch, layers)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     params = jax.tree.map(sds, pm.abstract(model_spec(cfg)))
     caches = jax.tree.map(sds, jax.eval_shape(
