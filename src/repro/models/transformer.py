@@ -320,11 +320,18 @@ def embed_tokens(params: dict, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
 
 
 def unembed(params: dict, h: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """Float32 logits.  The product reads ``h`` and the head in the dtype
+    they are stored in and accumulates in float32: an upcast copy of the
+    head would double the bytes read and, on the overlay, be materialized
+    as a placed cast every call.  Operands of different dtypes meet at
+    their common type, never below either."""
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", h.astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        w, spec = params["embed"], "bsd,vd->bsv"
     else:
-        logits = h.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+        w, spec = params["lm_head"], "bsd,dv->bsv"
+    dt = jnp.promote_types(h.dtype, w.dtype)
+    logits = jnp.einsum(spec, h.astype(dt), w.astype(dt),
+                        preferred_element_type=jnp.float32)
     if cfg.final_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     return shd.constrain_logical(logits, ("batch", None, "vocab"))

@@ -6,15 +6,18 @@ main path at real widths with ``interpret=False`` against the v5e topology
 description and check that the compiled HLO holds the Mosaic kernel
 (``tpu_custom_call``).  Nothing runs; a compile takes a second or two.
 
-The served decode step is compiled the same way, as the overlay's generic
-tier assembles it (about ten seconds each): its KV cache must be updated in
-place, with no relayout copy of the cache or of one layer's slice of it.
+The served decode step and a prefill chunk are compiled the same way, as
+the overlay's generic tier assembles them (about ten seconds each): the
+decode's KV cache must be updated in place, with no relayout copy of the
+cache or of one layer's slice of it, and neither may make a float32 copy
+of the output head.
 
 The topology is described inside a module-scoped fixture, never at import,
 so every pytest-xdist worker collects the same tests and only the worker
 that runs this file loads the TPU compiler.
 """
 
+import functools
 import os
 import re
 
@@ -128,30 +131,97 @@ def _copied_shapes(hlo: str) -> set[tuple[int, ...]]:
     return shapes
 
 
-@pytest.mark.parametrize("arch,batch", DECODE_CASES)
-def test_decode_updates_cache_in_place_for_v5e(arch, batch, one_chip,
-                                               no_compile_cache):
+@functools.cache
+def _compiled_step(arch: str, batch: int, program: str, one_chip):
+    """(cfg, caches, compiled) of the served ``decode`` (cache donated) or
+    of one 128-token ``prefill_chunk`` of a batch-1 prompt, as the overlay's
+    generic tier assembles it; each is compiled once for this module."""
     layers, max_len = 2, 1024
     cfg = _two_layers(arch, layers)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    i32 = lambda *shape: sds(jax.ShapeDtypeStruct(shape, jnp.int32))
     params = jax.tree.map(sds, pm.abstract(model_spec(cfg)))
+    rows = batch if program == "decode" else 1
     caches = jax.tree.map(sds, jax.eval_shape(
-        lambda: mdl.init_cache(cfg, batch, max_len)))
-    tok = sds(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
-    pos = sds(jax.ShapeDtypeStruct((batch,), jnp.int32))
-    step = lambda p, t, c, q: mdl.decode_step(p, cfg, t, c, positions=q)
-    graph = trace_to_graph(step, params, tok, caches, pos,
-                           name=f"{cfg.name}.decode").graph
+        lambda: mdl.init_cache(cfg, rows, max_len)))
+    if program == "decode":
+        args = (params, i32(rows, 1), caches, i32(rows))
+        step = lambda p, t, c, q: mdl.decode_step(p, cfg, t, c, positions=q)
+        # the cache's leaves follow routes, params and tokens
+        first = 1 + len(jax.tree.leaves(params)) + 1
+        donate = tuple(range(first, first + len(jax.tree.leaves(caches))))
+    else:
+        args = (params, i32(rows, 128), caches, i32())
+        step = lambda p, t, c, i: mdl.prefill_chunk(p, cfg, t, c, i)
+        donate = ()
+    graph = trace_to_graph(step, *args,
+                           name=f"{cfg.name}.{program}").graph
     routes = sds(jax.ShapeDtypeStruct((len(graph.edges()),), jnp.int32))
-    n_params, n_cache = len(jax.tree.leaves(params)), len(jax.tree.leaves(caches))
-    first = 1 + n_params + 1                    # after routes, params, tokens
-    donate = tuple(range(first, first + n_cache))
     compiled = jax.jit(build_kernel(graph), donate_argnums=donate).lower(
-        routes, *jax.tree.leaves((params, tok, caches, pos))).compile()
+        routes, *jax.tree.leaves(args)).compile()
+    return cfg, caches, compiled
 
+
+@pytest.mark.parametrize("arch,batch", DECODE_CASES)
+def test_decode_updates_cache_in_place_for_v5e(arch, batch, one_chip,
+                                               no_compile_cache):
+    cfg, caches, compiled = _compiled_step(arch, batch, "decode", one_chip)
+    layers, max_len = 2, 1024
     kv = (batch, cfg.num_kv_heads, max_len, cfg.resolved_head_dim)
     assert not _copied_shapes(compiled.as_text()) & {
         (layers, *kv), (1, *kv), kv}
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(caches))
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+# temp bytes of the same compiles with the head upcast to float32 before its
+# product (``unembed`` as it was), for the same described chip
+UPCAST_HEAD_TEMP = {
+    ("phi3-mini-3.8b", "decode"): 593_602_560,
+    ("phi3-mini-3.8b", "chunk"): 593_957_376,
+    ("minicpm-2b", "decode"): 1_133_699_072,
+    ("minicpm-2b", "chunk"): 1_134_086_144,
+    ("zamba2-7b", "decode"): 739_147_776,
+    ("zamba2-7b", "chunk"): 1_194_374_144,
+}
+
+
+def _results(hlo: str) -> set[tuple[str, str, tuple[int, ...]]]:
+    """(dtype, opcode, shape) of every instruction's result (tuple members
+    each), parameters included."""
+    out = set()
+    for line in hlo.splitlines():
+        m = re.search(r"%\S+ = (.*?) ([a-z][\w-]*)\(", line)
+        if m:
+            for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)):
+                out.add((dt, m.group(2),
+                         tuple(int(d) for d in dims.split(",") if d)))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("arch,batch", DECODE_CASES)
+def test_head_reads_bf16_weight_for_v5e(arch, batch, program, one_chip,
+                                        no_compile_cache):
+    """No value of the head's shape is float32: the product reads the bf16
+    weight, so no upcast copy is made or routed through a hop loop, and the
+    temp loses that copy.  No bf16 relayout copy of the head is left."""
+    cfg, _, compiled = _compiled_step(arch, batch, program, one_chip)
+    v, d = cfg.vocab_size, cfg.d_model
+    head = {(v, d), (d, v)}
+    results = _results(compiled.as_text())
+    assert not {r for r in results if r[0] == "f32" and r[2] in head}
+    assert not {r for r in results if r[0] == "bf16" and r[2] in head
+                and r[1] in ("copy", "copy-start")}
+
+    fall = (UPCAST_HEAD_TEMP[(arch, program)]
+            - compiled.memory_analysis().temp_size_in_bytes)
+    head_f32 = v * d * 4
+    if (arch, program) == ("zamba2-7b", "decode"):
+        # the peak is now set by other buffers: 261.6 MB of the f32 head's
+        # 458.8 MB left it, which is more than the head's bf16 half
+        assert fall >= head_f32 // 2
+    else:
+        # the f32 head sat on the peak; 1 MiB of buffer-assignment slack
+        assert fall >= head_f32 - 2 ** 20
