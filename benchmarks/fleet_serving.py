@@ -1,9 +1,9 @@
 """Fleet serving: aggregate throughput + tail latency, fleet vs one fabric.
 
 Saturating multi-tenant load: several tenants (distinct smoke archs) each
-run a :class:`ServeEngine` against ONE shared overlay, with prompt-length
-variants so every tenant owns a decode accelerator plus several prefill
-accelerators.  On a single 3x3 fabric the combined working set exceeds the
+run an :class:`EventLoopEngine` against ONE shared overlay, with prompt
+lengths in two chunk buckets so every tenant owns a decode accelerator
+plus two prefill-chunk accelerators.  On a single 3x3 fabric the combined working set exceeds the
 tile supply — every admission wave reclaims someone else's accelerator and
 repays its download (placement churn).  A 4-member :class:`FleetOverlay`
 places the same working set across fabrics (cost-score placement), keeps
@@ -29,7 +29,7 @@ from repro.configs.archs import smoke_config
 from repro.core import FleetOverlay, Overlay
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 SMOKE_TENANTS = ("phi3-mini-3.8b", "minicpm-2b")
 # zamba2/deepseek smoke configs trace+compile an order of magnitude slower
@@ -54,12 +54,12 @@ def _run(num_fabrics: int, tenants: tuple[str, ...], *,
          requests_per_tenant: int, prompt_lens: tuple[int, ...],
          max_new: int, batch: int, max_len: int) -> dict:
     overlay = _make_overlay(num_fabrics, len(tenants))
-    engines: list[ServeEngine] = []
+    engines: list[EventLoopEngine] = []
     for t, name in enumerate(tenants):
         cfg = smoke_config(name)
         params = pm.init(model_spec(cfg), jax.random.PRNGKey(t))
-        engines.append(ServeEngine(params, cfg, batch=batch, max_len=max_len,
-                                   overlay=overlay))
+        engines.append(EventLoopEngine(params, cfg, batch=batch,
+                                       max_len=max_len, overlay=overlay))
 
     # deterministic prompts (identical for the baseline and the fleet run)
     rng = np.random.default_rng(0)
@@ -119,8 +119,8 @@ def _run(num_fabrics: int, tenants: tuple[str, ...], *,
 
 def main(smoke: bool = False) -> list[str]:
     tenants = SMOKE_TENANTS if smoke else FULL_TENANTS
-    # two prompt-length variants per tenant: each tenant owns 3 residents
-    # (2 prefill + decode) of 3 tiles each (2-tile budget window + the LARGE
+    # two prompt-length buckets per tenant: each tenant owns 3 residents
+    # (2 prefill chunks + decode) of 3 tiles each (2-tile budget window + the LARGE
     # tile the attention op must own).  3 full-mode tenants want 27 tiles —
     # a single 3x3 fabric (9 tiles) churns every admission wave, while the
     # 4x(3x3) fleet (36 tiles) keeps everything resident WITH free headroom

@@ -55,12 +55,12 @@ def main(argv: list[str] | None = None) -> None:
     from benchmarks import (branch_speculation, chaos_serving,
                             dispatch_overhead, download_pipeline,
                             fig3_vmul_reduce, fleet_serving, isa_mix,
-                            overload_serving, pr_overhead, relocation,
-                            residency_churn, tile_granularity, warm_restart)
+                            pr_overhead, relocation, residency_churn,
+                            tile_granularity, warm_restart)
     modules = [fig3_vmul_reduce, pr_overhead, download_pipeline, isa_mix,
                tile_granularity, branch_speculation, residency_churn,
-               relocation, dispatch_overhead, fleet_serving, overload_serving,
-               chaos_serving, warm_restart]
+               relocation, dispatch_overhead, fleet_serving, chaos_serving,
+               warm_restart]
     print("name,us_per_call,derived")
     rows: list[str] = []
     failed = 0

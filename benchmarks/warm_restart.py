@@ -6,7 +6,7 @@ RESTARTED serving process rebuilds its working set by deserializing
 executables (milliseconds) instead of re-tracing and re-compiling them
 through XLA (seconds).  This benchmark measures exactly that boundary:
 
-* boot A — fresh store directory: a :class:`ServeEngine` warms up and
+* boot A — fresh store directory: an :class:`EventLoopEngine` warms up and
   serves one batch of requests, paying every trace + XLA compile.  The
   overlay closes cleanly (persists drain, measurement ledger saved).
 * boot B — same directory, new process state: an identical engine serves
@@ -33,7 +33,7 @@ from repro.configs.archs import smoke_config
 from repro.core import Overlay
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 ARCH = "phi3-mini-3.8b"
 
@@ -46,8 +46,8 @@ def _boot(store_dir: str, *, params, cfg, prompts: list[list[int]],
     first emitted token."""
     t0 = time.perf_counter()
     overlay = Overlay(3, 3, store_path=store_dir)
-    engine = ServeEngine(params, cfg, batch=batch, max_len=max_len,
-                         overlay=overlay)
+    engine = EventLoopEngine(params, cfg, batch=batch, max_len=max_len,
+                             overlay=overlay)
     engine.warmup(prompt_lens=tuple(sorted({len(p) for p in prompts})))
     for rid, prompt in enumerate(prompts):
         engine.submit(Request(rid=rid, prompt=prompt,

@@ -1,22 +1,24 @@
 """Batched serving example: continuous slot recycling on a shared fabric.
 
-Part 1 runs a reduced phi3-family model through the engine: prefill and
-decode are TWO separate accelerators resident on one overlay — ``overlay.jit``
-traces each, places them in disjoint tiles under a footprint budget, and
-holds the compiled steps in the bitstream cache.  Every tick after the
-first dispatches straight to the resident accelerator: no re-trace, no
-re-place, not even a cache walk (residency short-circuits above the cache).
+Part 1 runs a reduced phi3-family model through the engine: the prefill
+chunk and decode are TWO separate accelerators resident on one overlay —
+``overlay.jit`` traces each, places them in disjoint tiles under a
+footprint budget, and holds the compiled steps in the bitstream cache.
+Every tick after the first dispatches straight to the resident
+accelerator: no re-trace, no re-place, not even a cache walk (residency
+short-circuits above the cache).
 
-Part 2 shares ONE fabric between TWO models: both engines' prefill/decode
-accelerators co-reside, and the fabric report shows per-resident tile
-occupancy — the paper's multi-accelerator PR-region picture.
+Part 2 shares ONE fabric between TWO models: both engines' prefill-chunk
+and decode accelerators co-reside, and the fabric report shows
+per-resident tile occupancy — the paper's multi-accelerator PR-region
+picture.
 
 Part 3 turns on the asynchronous download pipeline
 (``Overlay(async_downloads=True)``): the engine prefetches the decode
-accelerator while the first prefill runs, early ticks are served by the
-traced-function fallback whenever a bitstream is still in flight, and the
-compiled accelerators swap in mid-stream — time-to-first-token no longer
-waits for any XLA compile.
+accelerator while the first prefill chunk runs, early ticks are served by
+the traced-function fallback whenever a bitstream is still in flight, and
+the compiled accelerators swap in mid-stream — time-to-first-token no
+longer waits for any XLA compile.
 
     PYTHONPATH=src python examples/serve_batched.py
 """
@@ -30,14 +32,15 @@ from repro.configs.archs import smoke_config
 from repro.core import Overlay
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 
 def run_single_model():
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
     overlay = Overlay(3, 3)
-    engine = ServeEngine(params, cfg, batch=4, max_len=96, overlay=overlay)
+    engine = EventLoopEngine(params, cfg, batch=4, max_len=96,
+                             overlay=overlay)
 
     rng = np.random.default_rng(0)
     n_requests = 10
@@ -72,8 +75,8 @@ def run_multi_model_shared_fabric():
     for seed, arch in enumerate(("phi3-mini-3.8b", "minicpm-2b")):
         cfg = smoke_config(arch)
         params = pm.init(model_spec(cfg), jax.random.PRNGKey(seed))
-        engines[arch] = ServeEngine(params, cfg, batch=2, max_len=48,
-                                    overlay=overlay)
+        engines[arch] = EventLoopEngine(params, cfg, batch=2, max_len=48,
+                                        overlay=overlay)
         for rid in range(3):
             engines[arch].submit(
                 Request(rid=rid, prompt=[1, 2, 3, 4, 5], max_new_tokens=8))
@@ -102,7 +105,8 @@ def run_async_pipeline():
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
     overlay = Overlay(3, 3, async_downloads=True)
-    engine = ServeEngine(params, cfg, batch=4, max_len=96, overlay=overlay)
+    engine = EventLoopEngine(params, cfg, batch=4, max_len=96,
+                             overlay=overlay)
 
     rng = np.random.default_rng(0)
     for rid in range(8):

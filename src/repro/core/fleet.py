@@ -145,7 +145,7 @@ class FleetJitAssembled:
         self.__doc__ = getattr(fn, "__doc__", None)
         fleet._register(self)
 
-    # ``ServeEngine.resize`` mutates ``tile_budget`` in place — propagate
+    # ``EventLoopEngine.resize`` mutates ``tile_budget`` in place — propagate
     # the new cap to every member-level wrapper so their next dispatch
     # repacks the resident via relocation, exactly like a single overlay.
     @property
@@ -342,7 +342,7 @@ class FleetOverlay:
         for idx, member in enumerate(self.members):
             member.reclaim_prefer = self._replica_preference(idx)
 
-    # -- member compatibility surface (ServeEngine and friends) ---------------
+    # -- member compatibility surface (EventLoopEngine and friends) -----------
     @property
     def grid(self):
         """The member fabric geometry (fleets are homogeneous for sizing

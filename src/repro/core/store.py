@@ -7,8 +7,8 @@ exactly the property that makes them durable: a kernel keyed by
 ``kernel_key`` — name, abstract signature, mesh descriptor, code fingerprint —
 is valid for any future process on the same jaxlib, regardless of where the
 fabric ends up placing it.  ``BitstreamStore`` persists those artifacts to a
-directory so a restarted ``ServeEngine`` (or a fresh ``FleetOverlay`` member)
-boots from disk instead of paying cold XLA compiles.
+directory so a restarted ``EventLoopEngine`` (or a fresh ``FleetOverlay``
+member) boots from disk instead of paying cold XLA compiles.
 
 Format (one file per artifact, named ``sha256(key).bits``):
 

@@ -1,4 +1,4 @@
-"""Serving launcher: batched requests through the ServeEngine.
+"""Serving launcher: batched requests through the EventLoopEngine.
 
     PYTHONPATH=src python -m repro.launch.serve \
         --arch phi3-mini-3.8b --smoke --requests 8 --batch 4
@@ -12,12 +12,12 @@ as a bitstream (paper C1/C3) instead of being jitted directly.
 the fleet cost score, hot ones replicate, and dispatches route to the
 least-loaded live copy.  Implies the overlay path.
 
-``--event-loop`` serves through the :class:`EventLoopEngine` (DESIGN.md
-§9): chunked power-of-two-bucketed prefill interleaved with decode ticks
-plus SLO-aware admission — ``--chunk`` sets the prefill chunk size,
-``--max-queue`` bounds queue depth, and ``--max-queue-delay`` (seconds)
-sheds requests that would miss their delay budget.  Shed requests and the
-engine's latency histograms are reported after the drain.
+The engine (DESIGN.md §9) interleaves chunked power-of-two-bucketed
+prefill with decode ticks behind SLO-aware admission: ``--chunk`` sets the
+prefill chunk size, ``--max-queue`` bounds queue depth, and
+``--max-queue-delay`` (seconds) sheds requests that would miss their delay
+budget.  Shed requests and the engine's latency histograms are reported
+after the drain.
 """
 
 from __future__ import annotations
@@ -34,7 +34,7 @@ from repro.core import FleetOverlay, Overlay
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 
 def main(argv=None) -> int:
@@ -57,11 +57,8 @@ def main(argv=None) -> int:
                          "overlay kernels are serialized there and a "
                          "restarted server warm-boots from disk instead of "
                          "recompiling (implies --overlay)")
-    ap.add_argument("--event-loop", action="store_true",
-                    help="serve through the EventLoopEngine (chunked "
-                         "bucketed prefill + SLO-aware admission)")
     ap.add_argument("--chunk", type=int, default=64,
-                    help="prefill chunk size (power of two; event loop only)")
+                    help="prefill chunk size (power of two)")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="shed submissions beyond this queue depth")
     ap.add_argument("--max-queue-delay", type=float, default=None,
@@ -81,15 +78,10 @@ def main(argv=None) -> int:
         overlay = Overlay(3, 3, store_path=args.store)
     else:
         overlay = None
-    if args.event_loop:
-        from repro.serving import EventLoopEngine
-        engine = EventLoopEngine(
-            params, cfg, batch=args.batch, max_len=args.max_len,
-            overlay=overlay, chunk=args.chunk, max_queue=args.max_queue,
-            max_queue_delay=args.max_queue_delay)
-    else:
-        engine = ServeEngine(params, cfg, batch=args.batch,
-                             max_len=args.max_len, overlay=overlay)
+    engine = EventLoopEngine(
+        params, cfg, batch=args.batch, max_len=args.max_len,
+        overlay=overlay, chunk=args.chunk, max_queue=args.max_queue,
+        max_queue_delay=args.max_queue_delay)
 
     rng = np.random.default_rng(args.seed)
     t0 = time.perf_counter()
@@ -104,12 +96,10 @@ def main(argv=None) -> int:
     tokens = sum(len(r.out) for r in done)
     print(f"[serve] {cfg.name}: {len(done)}/{args.requests} requests, "
           f"{tokens} tokens in {dt:.2f}s ({tokens/dt:.1f} tok/s)")
-    if args.event_loop:
-        shed = getattr(engine, "shed", [])
-        if shed:
-            print(f"[serve] shed {len(shed)} request(s): "
-                  f"{[(r.rid, r.shed_reason) for r in shed]}")
-        print(f"[serve] metrics: {engine.metrics()}")
+    if engine.shed:
+        print(f"[serve] shed {len(engine.shed)} request(s): "
+              f"{[(r.rid, r.shed_reason) for r in engine.shed]}")
+    print(f"[serve] metrics: {engine.metrics()}")
     if overlay is not None:
         print(f"[serve] overlay: {overlay.describe()}")
     for r in done[:3]:

@@ -9,6 +9,7 @@ exactly the way the paper assembles accelerators from bitstreams
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -199,6 +200,46 @@ def _current_index(cfg: ArchConfig, caches: dict):
             if "index" in entry:
                 return entry["index"][0]   # stacked (rep,) — all equal
     return jnp.zeros((), jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# The pooled cache of a serving engine: one batch row per slot
+# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _slot_spec(cfg: ArchConfig) -> dict:
+    # built once per config: admission reads it inside the serving loop,
+    # and an 81-layer spec takes ~1 ms to build
+    return cache_spec(cfg, 1, 1)
+
+
+def install_slot(cfg: ArchConfig, pool: dict, one: dict, slot: int) -> dict:
+    """Scatter the batch-1 cache ``one`` into row ``slot`` of ``pool``.
+
+    Each leaf's batch axis is the one ``cache_spec`` names ``"batch"``,
+    behind the stacked layer axis.  A leaf with no batch axis is a layer's
+    scalar ``index``, shared by every slot: it keeps the max (the ragged
+    decode reads each slot's own position instead)."""
+    def place(spec, p, o):
+        if "batch" not in spec.axes:
+            return jnp.maximum(p, o.astype(p.dtype))
+        return jax.lax.dynamic_update_slice_in_dim(
+            p, o.astype(p.dtype), slot, axis=spec.axes.index("batch"))
+
+    return jax.tree.map(place, _slot_spec(cfg), pool, one,
+                        is_leaf=pm.is_spec)
+
+
+def donation_probes(caches: dict) -> tuple:
+    """One self-attention K leaf and one SSD state leaf of ``caches`` (None
+    where the model has none): after a call that donates ``caches``, each
+    one's ``is_deleted()`` says whether that state was consumed."""
+    def first(name):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(caches):
+            if any(getattr(k, "key", None) == name for k in path):
+                return leaf
+        return None
+
+    return first("k"), first("ssm")
 
 
 # ---------------------------------------------------------------------------

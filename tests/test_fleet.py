@@ -12,7 +12,7 @@ from repro.configs.archs import smoke_config
 from repro.core import FleetOverlay, Overlay
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 X = jnp.arange(8, dtype=jnp.float32)
 Y = jnp.ones(8, jnp.float32)
@@ -242,7 +242,8 @@ def test_serve_engine_on_fleet_matches_single_overlay_tokens():
     prompts = [[1, 2, 3, 4], [5, 6, 7], [2, 4, 6]]
 
     def serve(overlay):
-        eng = ServeEngine(params, cfg, batch=2, max_len=32, overlay=overlay)
+        eng = EventLoopEngine(params, cfg, batch=2, max_len=32,
+                              overlay=overlay)
         for rid, p in enumerate(prompts):
             eng.submit(Request(rid=rid, prompt=list(p), max_new_tokens=3))
         return {r.rid: r.out for r in eng.run_until_drained()}
@@ -251,5 +252,6 @@ def test_serve_engine_on_fleet_matches_single_overlay_tokens():
     fleet = _fleet(2)
     got = serve(fleet)
     assert got == single                       # bit-identical token streams
-    assert fleet.describe()["fleet"]["placements"] >= 2   # prefill + decode
+    # the prefill chunk and decode
+    assert fleet.describe()["fleet"]["placements"] >= 2
     fleet.close()

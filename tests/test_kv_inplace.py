@@ -22,8 +22,7 @@ from repro.models import layers
 from repro.models import model as mdl
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
-from repro.serving.loop import EventLoopEngine
+from repro.serving import EventLoopEngine, Request
 
 MAX_LEN = 256
 # window edges (0, 127, 128, max_len - 1), a mid-window row, and two dead
@@ -107,10 +106,10 @@ PROMPTS = ((3, 5, 7), (11, 13, 17, 19, 23, 29, 31, 37, 41), (2, 4),
 
 
 @functools.cache
-def _serve(engine_cls, overlay: bool):
+def _serve(chunk: int, overlay: bool):
     """Serve PROMPTS to the end: (ids by request, engine, decode ticks)."""
-    eng = engine_cls(PARAMS, CFG, batch=3, max_len=32,
-                     overlay=Overlay(3, 3) if overlay else None)
+    eng = EventLoopEngine(PARAMS, CFG, batch=3, max_len=32, chunk=chunk,
+                          overlay=Overlay(3, 3) if overlay else None)
     decode_ticks = 0
     tick = eng._decode_tick
 
@@ -130,10 +129,11 @@ def _serve(engine_cls, overlay: bool):
     return {r.rid: r.out for r in done}, eng, decode_ticks
 
 
-@pytest.mark.parametrize("engine_cls", [ServeEngine, EventLoopEngine])
-def test_overlay_and_plain_engines_serve_identical_ids(engine_cls):
-    plain, _, _ = _serve(engine_cls, False)
-    served, eng, _ = _serve(engine_cls, True)
+# chunk 64 prefills every prompt in one chunk, chunk 4 in several
+@pytest.mark.parametrize("chunk", [4, 64])
+def test_overlay_and_plain_engines_serve_identical_ids(chunk):
+    plain, _, _ = _serve(chunk, False)
+    served, eng, _ = _serve(chunk, True)
     assert served == plain
     assert len(plain) == len(PROMPTS)
     failures = eng.overlay_failures()
@@ -143,7 +143,7 @@ def test_overlay_and_plain_engines_serve_identical_ids(engine_cls):
 
 @pytest.mark.parametrize("overlay", [False, True])
 def test_every_decode_tick_donates_its_cache(overlay):
-    _, eng, decode_ticks = _serve(EventLoopEngine, overlay)
+    _, eng, decode_ticks = _serve(64, overlay)
     m = eng.metrics()
     assert decode_ticks > 0
     assert m["kv_donated_ticks"] == decode_ticks

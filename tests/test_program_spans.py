@@ -18,8 +18,7 @@ from repro.configs.archs import smoke_config
 from repro.core import Overlay
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Request, ServeEngine
-from repro.serving.loop import EventLoopEngine
+from repro.serving import EventLoopEngine, Request
 
 CFG = smoke_config("phi3-mini-3.8b")
 PARAMS = pm.init(model_spec(CFG), jax.random.PRNGKey(0))
@@ -106,7 +105,7 @@ def test_event_loop_metrics_count_ticks():
 
 
 def test_serve_engine_tick_spans(tmp_path):
-    eng = ServeEngine(PARAMS, CFG, batch=2, max_len=32)
+    eng = EventLoopEngine(PARAMS, CFG, batch=2, max_len=32)
     eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2))
     eng.step()                      # compiles outside the profile
     eng.submit(Request(rid=1, prompt=[4, 5, 6], max_new_tokens=2))
@@ -115,8 +114,11 @@ def test_serve_engine_tick_spans(tmp_path):
     spans = _spans(tmp_path)
     steps = _named(spans, "engine.step")
     assert len(steps) == 2
-    (admit,) = _inside(spans, steps[0], "engine.admit")
-    assert len(_inside(spans, admit, "engine.install_stripe")) == 1
+    # the first tick admits request 1, prefills its one chunk, installs
+    # its stripe and decodes both slots
+    assert len(_inside(spans, steps[0], "engine.admit")) == 1
+    assert len(_inside(spans, steps[0], "engine.prefill_chunk")) == 1
+    assert len(_inside(spans, steps[0], "engine.install_stripe")) == 1
     for phase in ("engine.decode", "engine.sample", "engine.device_get",
                   "engine.retire"):
         assert len(_named(spans, phase)) == 2

@@ -288,7 +288,7 @@ def test_tile_budget_repack_relocates_without_redownload():
 
 
 def test_jit_tile_budget_resize_relocates_in_place():
-    # ServeEngine.resize() path: mutating a wrapper's tile_budget repacks
+    # EventLoopEngine.resize() path: mutating a wrapper's tile_budget repacks
     # the live resident on the next dispatch — relocation, not re-download
     ov = Overlay(3, 3, large_fraction=0.0)
     jitted = ov.jit(lambda x, y: x * 2.0 + y, name="resizable", tile_budget=2)
@@ -297,7 +297,7 @@ def test_jit_tile_budget_resize_relocates_in_place():
     acc = jitted.accelerator(x, x)
     assert len(set(acc.placement.assignment.values())) == 2
     ins = ov.cache.stats.insertions
-    jitted.tile_budget = 1                     # what ServeEngine.resize sets
+    jitted.tile_budget = 1                     # what EventLoopEngine.resize sets
     y1 = jitted(x, x)
     acc2 = jitted.accelerator(x, x)
     assert len(set(acc2.placement.assignment.values())) == 1

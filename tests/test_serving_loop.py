@@ -11,14 +11,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
 from repro.configs.archs import smoke_config
 from repro.core import FleetOverlay, Overlay
 from repro.models import model as mdl
 from repro.models import params as pm
 from repro.models.transformer import model_spec
-from repro.serving import Histogram, Request, ServeEngine
-from repro.serving.loop import EventLoopEngine
+from repro.serving import EventLoopEngine, Histogram, Request
 
 CFG = smoke_config("phi3-mini-3.8b")
 PARAMS = pm.init(model_spec(CFG), jax.random.PRNGKey(0))
@@ -47,11 +48,13 @@ def test_decode_tick_performs_one_host_transfer(monkeypatch):
     per-slot ``int(...)`` syncs (2 x batch device->host round-trips per
     tick).  The fused path must issue exactly ONE ``jax.device_get`` per
     tick, independent of batch size."""
-    engine = ServeEngine(PARAMS, CFG, batch=3, max_len=32)
+    engine = EventLoopEngine(PARAMS, CFG, batch=3, max_len=32)
     for rid in range(3):
         engine.submit(Request(rid=rid, prompt=[1 + rid, 2, 3],
-                              max_new_tokens=4))
-    engine.step()                     # admissions + first decode tick
+                              max_new_tokens=8))
+    for _ in range(3):                # admissions, one prefill chunk a tick
+        engine.step()
+    assert not engine._prefilling
 
     calls = []
     real = jax.device_get
@@ -70,7 +73,7 @@ def test_ragged_prompt_lengths_decode_at_correct_positions():
     index made every slot decode at the longest prompt's position — short
     prompts attended to garbage KV entries."""
     prompts = [[1, 2, 3], list(range(1, 10))]          # lengths 3 and 9
-    engine = ServeEngine(PARAMS, CFG, batch=2, max_len=32)
+    engine = EventLoopEngine(PARAMS, CFG, batch=2, max_len=32)
     for rid, p in enumerate(prompts):
         engine.submit(Request(rid=rid, prompt=p, max_new_tokens=4))
     done = {r.rid: r for r in engine.run_until_drained()}
@@ -87,14 +90,12 @@ def test_event_loop_matches_sync_engine_bit_exact():
     single token: padded chunk positions are causally masked and then
     overwritten by decode before any query reaches them."""
     prompts = [[7] * 5, [3] * 2, list(range(1, 10)), [11] * 13, [5]]
-    sync = ServeEngine(PARAMS, CFG, batch=2, max_len=32)
     loop = EventLoopEngine(PARAMS, CFG, batch=2, max_len=32, chunk=4)
-    for eng in (sync, loop):
-        for rid, p in enumerate(prompts):
-            eng.submit(Request(rid=rid, prompt=list(p), max_new_tokens=3))
-    want = {r.rid: r.out for r in sync.run_until_drained()}
+    for rid, p in enumerate(prompts):
+        loop.submit(Request(rid=rid, prompt=list(p), max_new_tokens=3))
     got = {r.rid: r.out for r in loop.run_until_drained()}
-    assert got == want
+    assert got == {rid: _reference_decode(p, 3)
+                   for rid, p in enumerate(prompts)}
 
 
 def test_event_loop_prefill_chunk_sizes_bounded_by_bucket_set():
@@ -116,6 +117,27 @@ def test_event_loop_prefill_chunk_sizes_bounded_by_bucket_set():
     engine.run_until_drained()
     assert sizes <= {1, 2, 4}                  # bucket set for chunk=4
     assert 4 in sizes                          # long prompts use full chunks
+
+
+@pytest.mark.parametrize("max_len,chunk,n", [(22, 16, 21), (20, 64, 17)])
+def test_event_loop_last_chunk_stays_inside_the_cache(max_len, chunk, n):
+    """Where ``max_len`` is not a multiple of ``chunk``, the padded last
+    chunk (8 at offset 16; 32 at offset 0) would pass the cache's end: it
+    is cut to the room left, so the prompt's K/V lands where a whole-prompt
+    prefill puts it."""
+    prompt = [(7 * k) % 50 + 1 for k in range(n)]
+    engine = EventLoopEngine(PARAMS, CFG, batch=1, max_len=max_len,
+                             chunk=chunk)
+    engine.submit(Request(rid=0, prompt=prompt, max_new_tokens=4))
+    (done,) = engine.run_until_drained()
+    assert done.out == _reference_decode(prompt, done.decode_steps,
+                                         max_len=max_len)
+    _, whole = mdl.prefill(PARAMS, CFG, jnp.asarray(prompt, jnp.int32)[None],
+                           mdl.init_cache(CFG, 1, max_len))
+    g, w = engine.caches["g0"]["0:dense"], whole["g0"]["0:dense"]
+    for name in ("k", "v"):               # (layer, batch, head, seq, dim)
+        np.testing.assert_array_equal(np.asarray(g[name])[..., :n, :],
+                                      np.asarray(w[name])[..., :n, :])
 
 
 def test_event_loop_warmup_downloads_every_accelerator_traffic_uses():

@@ -345,12 +345,12 @@ def test_serve_engine_requests_decode_specialization_eagerly():
     from repro.configs.archs import smoke_config
     from repro.models import params as pm
     from repro.models.model import model_spec
-    from repro.serving import Request, ServeEngine
+    from repro.serving import EventLoopEngine, Request
 
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
     ov = Overlay(4, 4, async_downloads=True)
-    engine = ServeEngine(params, cfg, batch=2, max_len=64, overlay=ov)
+    engine = EventLoopEngine(params, cfg, batch=2, max_len=64, overlay=ov)
     engine.submit(Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=4))
     done = engine.run_until_drained()
     assert len(done) == 1 and done[0].decode_steps == 4
@@ -358,7 +358,7 @@ def test_serve_engine_requests_decode_specialization_eagerly():
     assert ov.drain(120)
     tiers = {r.name: r.tier for r in ov.fabric.residents.values()}
     assert tiers[f"{cfg.name}.decode"] == "specialized"
-    assert tiers[f"{cfg.name}.prefill"] == "generic"
+    assert tiers[f"{cfg.name}.prefill_chunk"] == "generic"
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,7 @@ from repro.models import params as pm
 from repro.models.transformer import model_spec
 from repro.optim import adamw_init, adamw_update, cosine, wsd
 from repro.runtime import FailureInjector, Supervisor, TrainLoopConfig
-from repro.serving import Request, ServeEngine
+from repro.serving import EventLoopEngine, Request
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +199,7 @@ def test_serve_engine_greedy_matches_manual_decode():
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
     prompt = list(range(1, 9))
 
-    engine = ServeEngine(params, cfg, batch=2, max_len=64)
+    engine = EventLoopEngine(params, cfg, batch=2, max_len=64)
     engine.submit(Request(rid=0, prompt=prompt, max_new_tokens=5))
     done = engine.run_until_drained()
     # out = 1 prefill-produced token + max_new_tokens decode-step tokens
@@ -221,7 +221,7 @@ def test_serve_engine_greedy_matches_manual_decode():
 def test_serve_engine_batched_slots_recycle():
     cfg = smoke_config("minicpm-2b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(1))
-    engine = ServeEngine(params, cfg, batch=2, max_len=32)
+    engine = EventLoopEngine(params, cfg, batch=2, max_len=32)
     for rid in range(4):                       # 4 requests through 2 slots
         engine.submit(Request(rid=rid, prompt=[1, 2, 3], max_new_tokens=3))
     done = engine.run_until_drained()
@@ -237,7 +237,7 @@ def test_serve_engine_retires_on_decode_steps_not_prefill_token():
     batched decode steps."""
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
-    engine = ServeEngine(params, cfg, batch=1, max_len=64)
+    engine = EventLoopEngine(params, cfg, batch=1, max_len=64)
     engine.submit(Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=3))
 
     ticks = 0
@@ -256,7 +256,7 @@ def test_serve_engine_submit_rejects_oversized_prompt():
     engine needs len(prompt) + 1 <= max_len (one decode step of headroom)."""
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
-    engine = ServeEngine(params, cfg, batch=1, max_len=16)
+    engine = EventLoopEngine(params, cfg, batch=1, max_len=16)
     with pytest.raises(ValueError, match="max_len"):
         engine.submit(Request(rid=0, prompt=list(range(1, 17)),
                               max_new_tokens=1))   # len 16 == max_len: no room
@@ -275,7 +275,7 @@ def test_serve_engine_run_until_drained_raises_on_tick_exhaustion():
     like a drained one."""
     cfg = smoke_config("phi3-mini-3.8b")
     params = pm.init(model_spec(cfg), jax.random.PRNGKey(0))
-    engine = ServeEngine(params, cfg, batch=1, max_len=64)
+    engine = EventLoopEngine(params, cfg, batch=1, max_len=64)
     engine.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=30))
     with pytest.raises(RuntimeError, match="still"):
         engine.run_until_drained(max_ticks=2)

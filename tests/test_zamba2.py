@@ -34,8 +34,7 @@ from repro.models import model as mdl  # noqa: E402
 from repro.models import params as pm  # noqa: E402
 from repro.models import ssm as ssm_lib  # noqa: E402
 from repro.models.transformer import model_spec  # noqa: E402
-from repro.serving import Request  # noqa: E402
-from repro.serving.loop import EventLoopEngine  # noqa: E402
+from repro.serving import EventLoopEngine, Request  # noqa: E402
 
 SEED = 2**31 + 161
 M, H = "mamba", "hybrid"
